@@ -26,13 +26,9 @@ it, so the full bench gates ``speedup_mean ≥ STREAM_SPEEDUP_GATE``; the
 answers of the two paths must agree either way. Prints a
 ``service-streaming,...`` CSV line into the same report.
 
-The 1-vs-N forced-host-devices comparison runs the same measurement in a
-subprocess under ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-(device count is process-global in XLA, so a second process is the only
-clean way to get both legs): on CPU runners the N-way leg exercises the
-sharded drain path end-to-end — the number is a *functional* check of the
-mesh pipeline, not a speedup claim, since N forced host devices split the
-same cores. ``--inner`` is that subprocess entry point.
+The service shards its drains over every device this process sees
+(``DPService``'s auto mesh); one process drives them all, so the bench
+never starts a child that would need the accelerator its parent holds.
 
 ``--telemetry-gate`` is the CI overhead gate: the same traffic with
 telemetry ``off`` vs ``spans`` (routing feedback disabled and the
@@ -47,18 +43,14 @@ from __future__ import annotations
 import hashlib
 import json
 import os
-import subprocess
-import sys
 import time
 from typing import Optional
 
 import numpy as np
 
 N_REQUESTS = 256
-FORCED_DEVICES = 8
 UNIQUE_FRACTION = 3          # ~N/3 unique instances → repeats hit the cache
 RECONSTRUCT_EVERY = 4        # every 4th request asks for a decoded solution
-SUBPROCESS_TIMEOUT_S = 600
 #: telemetry-gate budget: spans-mode wall ≤ off-mode wall × (1 + fraction),
 #: with an absolute slack floor so short walls don't gate on timer noise
 GATE_OVERHEAD_FRACTION = 0.05
@@ -333,42 +325,6 @@ def _csv_streaming(row: dict) -> None:
           f"{row['prefix_hit_rate']},{int(row['ok'])}")
 
 
-def _subprocess_leg(n_requests: int, devices: int) -> dict:
-    """Re-run ``_measure`` under forced host devices in a child process."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={devices}"
-                        ).strip()
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env["PYTHONPATH"] = os.pathsep.join(
-        p for p in [os.path.join(root, "src"), env.get("PYTHONPATH")] if p)
-    # a crash, hang, or garbled output in the sharded leg is a FAILURE of
-    # this bench — the whole point of the leg is to prove the sharded path
-    # end-to-end, so nothing here degrades to a silent skip
-    try:
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks.dp_service_bench", "--inner",
-             "--requests", str(n_requests)],
-            capture_output=True, text=True, cwd=root, env=env,
-            timeout=SUBPROCESS_TIMEOUT_S, check=True)
-    except subprocess.CalledProcessError as exc:
-        raise SystemExit(
-            f"forced-{devices}-device service leg crashed "
-            f"(exit {exc.returncode}); stderr tail:\n"
-            + "\n".join((exc.stderr or "").strip().splitlines()[-15:]))
-    except subprocess.TimeoutExpired:
-        raise SystemExit(f"forced-{devices}-device service leg hung "
-                         f"(> {SUBPROCESS_TIMEOUT_S}s)")
-    try:
-        lines = [ln for ln in out.stdout.strip().splitlines() if ln]
-        return json.loads(lines[-1])
-    except (IndexError, json.JSONDecodeError):
-        raise SystemExit(
-            f"forced-{devices}-device service leg produced no metrics row; "
-            f"stdout tail:\n"
-            + "\n".join(out.stdout.strip().splitlines()[-5:]))
-
-
 def _telemetry_summary(telemetry) -> dict:
     """Compact, committable digest of the registry: counters/gauges plus
     count/p50/p99 per histogram — no span bodies, no audit rows (those stay
@@ -388,19 +344,15 @@ def _telemetry_summary(telemetry) -> dict:
 def run(out_path: str = "BENCH_dp_service.json",
         telemetry_out_path: str = "TELEMETRY_dp_service.json",
         telemetry_summary_path: str = "TELEMETRY_dp_service_summary.json",
-        n_requests: int = N_REQUESTS, forced_devices: int = FORCED_DEVICES,
-        subprocess_leg: bool = True, check_perf: bool = True,
+        n_requests: int = N_REQUESTS, check_perf: bool = True,
         streaming: bool = True,
         streaming_cfg: Optional[dict] = None) -> dict:
-    import jax
-
     from repro.dp import telemetry
 
     legs = [_measure(n_requests)]
     _csv(legs[0])
     if telemetry_out_path:
-        # the CI artifact: full spans/metrics/audit state of the local leg
-        # (saved before the subprocess leg — a child crash must not lose it)
+        # the CI artifact: full spans/metrics/audit state of the leg
         print(f"# wrote {telemetry.save_snapshot(telemetry_out_path)}")
     if telemetry_summary_path:
         # the committed file: small enough to diff run-over-run
@@ -408,13 +360,7 @@ def run(out_path: str = "BENCH_dp_service.json",
             json.dump(_telemetry_summary(telemetry), f, indent=1,
                       default=str)
         print(f"# wrote {os.path.abspath(telemetry_summary_path)}")
-    if subprocess_leg and jax.device_count() != forced_devices:
-        legs.append(_subprocess_leg(n_requests, forced_devices))
-        _csv(legs[1])
     report = {"legs": legs, "n_requests": n_requests}
-    if len(legs) == 2:
-        report["throughput_ratio_Ndev_vs_1"] = round(
-            legs[1]["req_per_s"] / max(legs[0]["req_per_s"], 1e-9), 3)
     if streaming:
         report["streaming"] = _measure_streaming(**(streaming_cfg or {}))
         _csv_streaming(report["streaming"])
@@ -500,23 +446,18 @@ def telemetry_gate(n_requests: int = N_REQUESTS,
 if __name__ == "__main__":
     import argparse
 
+    from repro.jax_cache import use_persistent_cache
+
+    use_persistent_cache()
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--inner", action="store_true",
-                    help="subprocess mode: measure this process's devices "
-                         "and print one JSON row")
     ap.add_argument("--requests", type=int, default=N_REQUESTS)
-    ap.add_argument("--no-subprocess", action="store_true",
-                    help="skip the forced-N-devices comparison leg")
     ap.add_argument("--no-streaming", action="store_true",
                     help="skip the append-heavy streaming-session leg")
     ap.add_argument("--telemetry-gate", action="store_true",
                     help="run the off-vs-spans overhead/transparency gate "
                          "instead of the throughput legs")
     args = ap.parse_args()
-    if args.inner:
-        print(json.dumps(_measure(args.requests)))
-    elif args.telemetry_gate:
+    if args.telemetry_gate:
         telemetry_gate(args.requests)
     else:
-        run(n_requests=args.requests, subprocess_leg=not args.no_subprocess,
-            streaming=not args.no_streaming)
+        run(n_requests=args.requests, streaming=not args.no_streaming)

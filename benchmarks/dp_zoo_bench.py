@@ -34,7 +34,7 @@ REPEATS = 3
 #: host timings flip near-tied routes run to run (the PR-4 regret
 #: regression was mostly this)
 CALIBRATE_REPEATS = 5
-#: triangular sizes of the large-n leg — the regime the tiled HBM-resident
+#: triangular sizes of the large-n leg — the regime the tiled HBM-streaming
 #: kernels exist for (beyond any VMEM-resident table)
 LARGE_N = (256, 512, 1024)
 MEDIAN_REGRET_GATE = 1.5
@@ -53,7 +53,7 @@ def _time(fn, repeats: int = REPEATS) -> float:
 
 def _large_n_leg(sizes) -> list:
     """Triangular large-n leg: the regime past every VMEM-resident kernel.
-    Times the plain jnp wavefront against the tiled HBM-resident route
+    Times the plain jnp wavefront against the tiled HBM-streaming route
     (``kernel_tiled_wavefront``) on random f32 weight tables (bit-equality
     cross-checked), and the fused single-launch ``reconstruct`` against the
     classic two-dispatch solve+traceback. One timed repeat after warmup —
@@ -254,6 +254,9 @@ def run(out_path: str = "BENCH_dp_zoo.json", sizes=None, batch=None,
 if __name__ == "__main__":
     import argparse
 
+    from repro.jax_cache import use_persistent_cache
+
+    use_persistent_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--calibrate", action="store_true",
                     help="measure every cell into the autotune table first "

@@ -37,13 +37,10 @@ def main(smoke: bool = False, check_dispatch: bool = False) -> None:
                          check_dispatch=check_dispatch)
     print("# DP service — sharded continuous-batching serving tier "
           "(DESIGN.md §7)")
-    # smoke: in-process leg only — the forced-8-device comparison pays a
-    # second jax startup, which the dedicated CI sharded-test leg covers;
-    # the streaming leg shrinks to a geometry that still extends by <10%
-    # per append but keeps cold-solve warm-up cheap
+    # smoke: the streaming leg shrinks to a geometry that still extends by
+    # <10% per append but keeps cold-solve warm-up cheap
     if smoke:
-        dp_service_bench.run(out_path="", n_requests=64,
-                             subprocess_leg=False, check_perf=False,
+        dp_service_bench.run(out_path="", n_requests=64, check_perf=False,
                              streaming_cfg=dict(rows=256, base=512, k=32,
                                                 n_appends=3))
     else:
@@ -53,6 +50,9 @@ def main(smoke: bool = False, check_dispatch: bool = False) -> None:
 
 
 if __name__ == "__main__":
+    from repro.jax_cache import use_persistent_cache
+
+    use_persistent_cache()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="reduced sizes, skip speedup-threshold assertions "

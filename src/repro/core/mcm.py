@@ -89,21 +89,20 @@ def diag_of(c: int, n: int) -> int:
 # numpy oracle (CLRS 15.2)
 # ---------------------------------------------------------------------------
 def mcm_reference(dims) -> tuple[np.ndarray, np.ndarray]:
-    """O(n³) DP. Returns (m, split): m[i][j] = min cost of A_i..A_j."""
+    """O(n³) DP in float64, vectorized over each diagonal's cells and
+    splits (O(n) numpy steps). Returns (m, split): m[i][j] = min cost of
+    A_i..A_j and its first-occurrence best split s."""
     p = np.asarray(dims, dtype=np.float64)
     n = len(p) - 1
     m = np.zeros((n, n))
     split = np.full((n, n), -1, dtype=np.int64)
     for d in range(1, n):
-        for i in range(n - d):
-            j = i + d
-            best, bs = np.inf, -1
-            for s in range(i, j):
-                c = m[i, s] + m[s + 1, j] + p[i] * p[s + 1] * p[j + 1]
-                if c < best:
-                    best, bs = c, s
-            m[i, j] = best
-            split[i, j] = bs
+        i = np.arange(n - d)[:, None]               # cells (i, i + d)
+        s = i + np.arange(d)[None, :]               # splits s = i .. i+d-1
+        c = m[i, s] + m[s + 1, i + d] + p[i] * p[s + 1] * p[i + d + 1]
+        best = np.argmin(c, axis=1)
+        m[i[:, 0], i[:, 0] + d] = c[np.arange(n - d), best]
+        split[i[:, 0], i[:, 0] + d] = i[:, 0] + best
     return m, split
 
 

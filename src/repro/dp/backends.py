@@ -70,6 +70,19 @@ def drain_trace_log() -> list:
     return out
 
 
+def stack_bucket(arrays, sharding=None):
+    """Stack a bucket's per-instance arrays along a new batch axis. A
+    single-device drain stacks on the device. A sharded drain stacks on the
+    host and lets ``ShardContext.place`` send each device its lanes:
+    stacking on the device first would land the whole bucket on the
+    default device before the split."""
+    import jax.numpy as jnp
+
+    if sharding is None:
+        return jnp.stack([jnp.asarray(a) for a in arrays])
+    return sharding.place(np.stack([np.asarray(a) for a in arrays]))
+
+
 def lru_put(cache: "OrderedDict", key, value, max_entries: int):
     """Insert-or-refresh on an OrderedDict used as an LRU, evicting the
     stalest entries past ``max_entries``."""
@@ -256,12 +269,11 @@ def linear_backend(name: str, jax_fn: Callable, cost: Callable,
             return sharding.wrap(call)
 
         cached = lru_cached(_BATCH_CACHE, key, build, _BATCH_CACHE_MAX)
-        place = sharding.place if sharding is not None else (lambda x: x)
-        inits = place(jnp.stack([jnp.asarray(s.init) for s in specs]))
+        inits = stack_bucket([s.init for s in specs], sharding)
         if spec0.weights is None:
             return cached(inits)
-        return cached(inits, place(
-            jnp.stack([jnp.asarray(s.weights) for s in specs])))
+        return cached(inits, stack_bucket([s.weights for s in specs],
+                                          sharding))
 
     def _batch_key(specs, sharding) -> tuple:
         shard_tag = sharding.cache_suffix() if sharding is not None else ()
@@ -327,9 +339,7 @@ def triangular_tab_backend(name: str, jax_fn: Callable, cost: Callable,
                 return jax.jit(call)
             return sharding.wrap(call)
 
-        wtabs = jnp.stack([jnp.asarray(s.weights) for s in specs])
-        if sharding is not None:
-            wtabs = sharding.place(wtabs)
+        wtabs = stack_bucket([s.weights for s in specs], sharding)
         return lru_cached(_BATCH_CACHE, key, build, _BATCH_CACHE_MAX)(wtabs)
 
     def _batch_key(specs, sharding) -> tuple:
@@ -424,10 +434,7 @@ def grid_backend(name: str, jax_fn: Callable, cost: Callable,
             return sharding.wrap(call)
 
         cached = lru_cached(_BATCH_CACHE, key, build, _BATCH_CACHE_MAX)
-        place = sharding.place if sharding is not None else (lambda x: x)
-        stacked = tuple(place(jnp.stack([jnp.asarray(a) for a in slot]))
-                        for slot in slots)
-        return cached(*stacked)
+        return cached(*(stack_bucket(slot, sharding) for slot in slots))
 
     def _batch_key(specs, sharding) -> tuple:
         shard_tag = sharding.cache_suffix() if sharding is not None else ()

@@ -181,13 +181,17 @@ def linear_companion_scan_schedule(spec,
 
 def linear_kernel_blocked_schedule(spec, route: str = "kernel_blocked",
                                    block: int = 512) -> ScheduleModel:
-    """The VMEM-resident Pallas pipeline keeps the jnp blocked schedule;
-    its padded table tail (``n + a_k`` cells) absorbs the last block's
-    spill, so no real cell is ever clobbered."""
+    """The VMEM-resident Pallas pipeline: the blocked schedule at the
+    kernel's own step block (a power of two ≤ ``min(a_k, block, 128)``,
+    from ``chunk_geometry``); each block's store is lane-masked to its own
+    cells, so nothing else is ever clobbered."""
+    from repro.kernels.sdp_pipeline import chunk_geometry
+
+    geom = chunk_geometry(spec.offsets, spec.n, block=block, resident=True)
     return linear_blocked_schedule(
-        spec, route=route, block=block, kind="blocked_vmem",
-        notes="pallas kernel; last-block spill lands in the padded tail, "
-              "outside the real table")
+        spec, route=route, block=geom["block"], kind="blocked_vmem",
+        notes=f"pallas kernel, step block {geom['block']}; lane-masked "
+              "block stores")
 
 
 def chunk_carry_invariants(offsets, geom: dict) -> tuple:
@@ -398,7 +402,7 @@ def mcm_kernel_schedule(spec, route: str = "kernel_wavefront"
     from repro.kernels.mcm_pipeline import _geometry
 
     n = spec.n
-    L, _size = _geometry(n)
+    L, _rows = _geometry(n)
     cells = num_cells(n)
     clobbers = []
     for d in range(1, n):
@@ -415,34 +419,35 @@ def mcm_kernel_schedule(spec, route: str = "kernel_wavefront"
 
 def mcm_tiled_schedule(spec, route: str = "kernel_tiled_wavefront",
                        budget: Optional[int] = None) -> ScheduleModel:
-    """HBM-resident tiled triangular solver (``kernels.mcm_tiled``): the
-    wavefront consume/finalize order at diagonal granularity (band tiles
-    and candidate tiles sub-step within one diagonal, all reads on strictly
-    earlier diagonals), plus the DMA double-buffering invariants: the slot
-    pool must cover the reducing tile and every in-flight prefetch, and
-    the tile plan must fit the double-buffered VMEM budget."""
+    """Tiled triangular solver with HBM-streamed weights
+    (``kernels.mcm_tiled``): the wavefront consume/finalize order at
+    diagonal granularity (split tiles sub-step within one diagonal, all
+    reads on strictly earlier diagonals, one exact-length store per
+    diagonal), plus the pipelining invariants: the buffer pool must cover
+    the folding tile and every in-flight prefetch, and the buffered weight
+    tiles must fit the VMEM budget."""
     from repro.kernels import mcm_tiled as _mt
 
     if budget is None:
         from repro.kernels.ops import vmem_budget_bytes
 
         budget = vmem_budget_bytes()
-    T, E = _mt._tile_plan(spec.n, budget=budget)
-    cap = max(16, budget // _mt._BYTES_PER_TILE_ELEM)
+    plan = _mt.kernel_plan(spec.n, budget=budget)
     invariants = (
         ("dma_slots_cover_prefetch",
          _mt.DMA_SLOTS >= _mt.PREFETCH_DEPTH + 1,
          f"slots={_mt.DMA_SLOTS}, in-flight prefetches="
          f"{_mt.PREFETCH_DEPTH}"),
-        ("tile_plan_within_budget", T * E <= cap,
-         f"T={T}, E={E}, T*E={T * E}, cap={cap} "
-         f"(budget={budget} / {_mt._BYTES_PER_TILE_ELEM} B per elem)"),
+        ("tile_plan_within_budget",
+         plan["E"] == 1 or plan["tile_bytes"] <= budget,
+         f"E={plan['E']} splits/tile, buffered tiles "
+         f"{plan['tile_bytes']} B, budget={budget}"),
     )
     return triangular_wavefront_schedule(
         spec, route=route, kind="wavefront_tiled_dma",
         invariants=invariants,
-        notes=f"tile plan T={T}, E={E}; per-diagonal band tiles with "
-              "double-buffered candidate DMA")
+        notes=f"E={plan['E']} splits per weight tile, J={plan['J']} tiles "
+              "per diagonal; double-buffered weight tiles")
 
 
 # ---------------------------------------------------------------------------
@@ -501,46 +506,20 @@ def grid_wavefront_schedule(spec, route: str = "grid_wavefront",
 
 def grid_kernel_schedule(spec, route: str = "kernel_grid") -> ScheduleModel:
     """The frontier-major Pallas kernel (``kernels.grid_pipeline``): the
-    wavefront schedule plus the contiguous-layout spill discipline. Every
-    front writes a padded ``Lf``-lane slice; spill lanes land in later
-    fronts' buffer positions. Spilled *preset* cells are immediately
-    restored by the same blended write (``where(preset, s0, acc)`` reads
-    the spilled positions' own preset value/mask), so only non-preset
-    spill cells are clobbers — each rewritten by its own front's step.
-    Geometry (pad, lane count, position permutation) comes from the
-    kernel's own helpers."""
-    dep = spec.schedule_model()
-    written = _grid_written_planes(spec)
+    wavefront schedule plus each variant's write discipline. ``antidiag``
+    stores exactly its front's cells (lane-masked windows), so nothing is
+    clobbered. ``spandiag`` writes a padded ``L``-lane window per span
+    diagonal whose spill lanes land in *later* diagonals' cells — modeled as
+    clobbers, each rewritten by its own diagonal's step. Geometry comes from
+    the kernel's own helper."""
     clobbers = []
-    if spec.schedule == "antidiag":
-        from repro.kernels.grid_pipeline import _ad_positions
-
-        R, C = spec.rows, spec.cols
-        per = R * C
-        Lf = min(R, C)
-        pos = _ad_positions(R, C)               # row-major cell -> position
-        cell_of_pos = {int(q): rm for rm, q in enumerate(pos)}
-        base = [0] * (R + C)
-        for t in range(R + C - 1):
-            c0, c1 = max(0, t - R + 1), min(t, C - 1)
-            base[t + 1] = base[t] + (c1 - c0 + 1)
-        for t in range(1, R + C - 1):
-            c0, c1 = max(0, t - R + 1), min(t, C - 1)
-            width = c1 - c0 + 1
-            for q in range(base[t] + width, base[t] + Lf):
-                rm = cell_of_pos.get(q)
-                if rm is None:
-                    continue                     # tail padding
-                for p in written:
-                    cell = p * per + rm
-                    if cell not in dep.preset:   # preset lanes re-blend
-                        clobbers.append((t - 1, cell))
-    else:
+    if spec.schedule != "antidiag":
         from repro.kernels.grid_pipeline import _span_geometry
 
+        written = _grid_written_planes(spec)
         n = spec.rows
         per = num_cells(n)
-        L, _size = _span_geometry(n)
+        L, _rows = _span_geometry(n)
         for d in range(1, n):
             off = lin_index(0, d, n)
             for q in range(off + (n - d), off + L):
@@ -550,6 +529,6 @@ def grid_kernel_schedule(spec, route: str = "kernel_grid") -> ScheduleModel:
     return grid_wavefront_schedule(
         spec, route=route, kind=f"grid_wavefront_padded[{spec.schedule}]",
         clobbers=tuple(clobbers),
-        notes="padded frontier writes; non-preset spill lanes are "
-              "later-front cells rewritten by their own step, preset "
-              "lanes re-blend from the preset buffers")
+        notes="antidiag: exact front writes; spandiag: padded diagonal "
+              "writes whose spill lanes are later-diagonal cells rewritten "
+              "by their own step")

@@ -124,12 +124,11 @@ class ShardContext:
         device vmaps its own shard with the identical per-lane program, so
         the gathered result is bit-identical to the unsharded call."""
         import jax
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         p = P(self.axis)
-        return jax.jit(shard_map(call, mesh=self.mesh, in_specs=p,
-                                 out_specs=p, check_rep=False))
+        return jax.jit(jax.shard_map(call, mesh=self.mesh, in_specs=p,
+                                     out_specs=p, check_vma=False))
 
 
 class ShardedDPEngine(DPEngine):

@@ -6,8 +6,8 @@ pure-jnp oracle in ``ref.py`` and a dispatching wrapper in ``ops.py``.
                           (weighted + arg-emitting variants, DESIGN.md §4)
                           plus the HBM-streaming chunked variant (no size cap)
   * ``mcm_pipeline``    — VMEM-resident diagonal-pipeline triangular solver
-  * ``mcm_tiled``       — HBM-resident tiled triangular solver with
-                          double-buffered DMA and fused traceback (§4/§5)
+  * ``mcm_tiled``       — tiled triangular solver with HBM-streamed,
+                          double-buffered weights and fused traceback (§4/§5)
   * ``grid_pipeline``   — VMEM-resident frontier-major wavefront solver for
                           the grid family (antidiag/spandiag, DESIGN.md §9)
   * ``chunked_scan``    — gated linear recurrence (SSM/RWKV layers)
@@ -31,7 +31,7 @@ from repro.kernels import ops, ref  # noqa: F401
 # serve a program traced under the old mode (DESIGN.md §4).
 #
 # ``kernel_tiled`` (linear, chunked HBM-streaming) and
-# ``kernel_tiled_wavefront`` (triangular, HBM-resident tiled + fused
+# ``kernel_tiled_wavefront`` (triangular, HBM-streamed weights + fused
 # traceback) have NO supports() size cap — the streaming window is sized
 # from ``ops.vmem_budget_bytes()`` (env ``REPRO_VMEM_BUDGET``), which is
 # folded into the cache tag alongside the mode so a budget override never
@@ -53,7 +53,7 @@ def _tiled_mode_factor() -> float:
     """Per-mode factor for the HBM-streaming tiled routes. Slightly worse
     than the VMEM-resident kernels where those fit (DMA orchestration
     overhead) but the only kernel routes with no size cap; the interpreter
-    penalty is harsher still — per-tile DMAs are Python loops there."""
+    penalty is harsher still — per-tile grid steps are Python loops there."""
     mode = ops.kernel_mode()
     if mode == "pallas":
         return 0.6
@@ -67,22 +67,22 @@ def _on_kernel_path() -> bool:
 
 
 def _linear_vmem_bytes(spec) -> int:
-    """f32 working set of the (weighted, arg-emitting) S-DP kernel: padded
-    table + int32 arg table + optional (n, k) weight slab, all VMEM-resident."""
-    n_pad = spec.n + int(spec.offsets[-1])           # ≤ one block of padding
-    k = len(spec.offsets) if spec.weights is not None else 0
-    return 4 * n_pad * (2 + k)
+    """Working set of the (weighted, arg-emitting) VMEM-resident S-DP
+    kernel: window, weight rows, cost and arg outputs. Geometry comes from
+    the kernel itself so the gate can't diverge from the real buffers."""
+    from repro.kernels.sdp_pipeline import resident_vmem_bytes
+
+    return resident_vmem_bytes(spec.offsets, spec.n,
+                               weighted=spec.weights is not None)
 
 
 def _triangular_vmem_bytes(spec) -> int:
-    """f32 working set of the triangular kernel: padded cost + arg tables
-    plus the dense (cells, n-1) weight table (the dominant ~2n³ bytes term).
-    Geometry comes from the kernel itself so the gate can't diverge from the
-    real buffer layout."""
-    from repro.kernels.mcm_pipeline import _geometry
+    """Working set of the triangular kernel: the weight planes (the
+    dominant ~2n³ bytes term) plus the cost and arg tables, from the
+    kernel's own geometry."""
+    from repro.kernels.mcm_pipeline import vmem_bytes
 
-    lanes, size = _geometry(spec.n)
-    return 4 * size * (2 + lanes)
+    return vmem_bytes(spec.n)
 
 
 def _kernel_blocked_cost(spec) -> float:
@@ -189,5 +189,5 @@ _dp_backends.register(_dp_backends.triangular_tab_backend(
     cache_tag=_mode_tag,
     schedule=_sched.mcm_tiled_schedule,
     env_sensitive=_KERNEL_ENV,
-    doc="ops.mcm_tiled: HBM-resident tiled triangular solver, per-tile "
+    doc="ops.mcm_tiled: tiled triangular solver, HBM-streamed per-tile "
         "weight DMA, fused in-launch traceback; no size cap"))

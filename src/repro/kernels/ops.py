@@ -172,7 +172,7 @@ def sdp_chunked_with_args(init, offsets: tuple, op: str, n: int,
 
 
 def mcm_tiled(wtab, n: int):
-    """Triangular table solve with HBM-resident tables (DESIGN.md §4): the
+    """Triangular table solve with HBM-streamed weights (DESIGN.md §4): the
     double-buffered tiled Pallas kernel on the kernel path, the equivalent
     banded-tile jnp body elsewhere. No table-size cap on any path."""
     mode = kernel_mode()

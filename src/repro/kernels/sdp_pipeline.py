@@ -1,33 +1,38 @@
 """Pallas TPU kernel: blocked pipelined S-DP solver (the paper's Fig. 2 on TPU).
 
 The GPU pipeline finalizes 1 element/step with k threads; the TPU-native
-reading (DESIGN.md §2) finalizes a block of ``B = min(a_k, block)`` elements
-per step: all reads for block ``[t, t+B)`` use offsets ``≥ a_k ≥ B`` and hence
-touch only finalized elements, so each step is k static-offset VMEM slices +
-a tree-⊗ + one store — no gather, no conflicts, exactly the property Theorem 1
-buys on GPU.
+reading (DESIGN.md §2) finalizes a block of ``B`` elements per step, ``B`` a
+power of two no larger than ``min(a_k, block, 128)``: all reads for block
+``[t, t+B)`` use offsets ``≥ a_k ≥ B`` and hence touch only finalized
+elements, so each step is k shifted window reads + a fold + one store — no
+gather, no conflicts, exactly the property Theorem 1 buys on GPU.
 
-The whole table lives in VMEM (one f32 table of 2²⁰ elements = 4 MiB; VMEM is
-~16 MiB on v5e) and the block loop runs *inside* the kernel, so HBM traffic is
-one load + one store of the table regardless of k — versus O(nk) HBM touches
-for the naive form. Tables beyond VMEM stream through
-:func:`sdp_chunked_pallas` below (DESIGN.md §4): the grid walks C-cell chunks
-sequentially, BlockSpec pipelining streams each chunk's ``(C, k)`` weight tile
-HBM→VMEM double-buffered (the ``chunked_scan`` idiom), and a persistent
-``(a_1 + C)`` VMEM window carries the inter-chunk boundary — only the last
-``a_1`` finalized cells, the whole dependency horizon of the recurrence — so
-VMEM holds O(a_1 + C) regardless of n and there is no size cap at all.
+Layout (``kernels/rows.py``): the table is a ``(rows, 128)`` VMEM window,
+cell ``s`` at row ``s // 128``, lane ``s % 128``. A read at ``s - a_j`` is a
+two-row load plus a lane rotation; a block never crosses a row (``B`` divides
+128 and blocks start at multiples of ``B``), so its store is one masked row
+write. Weights arrive transposed, ``(k, rows, 128)`` — lane ``j``'s weights
+for a block are a slice of one row.
+
+One kernel serves both routes. The grid walks C-cell chunks sequentially
+(C a multiple of 128); BlockSpec pipelining streams each chunk's ``(k, C)``
+weight tile HBM→VMEM double-buffered and the finished chunk back out, while a
+persistent ``(A + C)``-cell VMEM window carries the inter-chunk boundary —
+the last ``A ≥ a_1`` finalized cells (whole rows), the whole dependency
+horizon of the recurrence. :func:`sdp_pipeline_pallas` is the one-chunk case
+(the VMEM-resident table: one HBM load + one store regardless of k);
+:func:`sdp_chunked_pallas` sizes C from a VMEM budget, so VMEM holds
+O(a_1 + C·k) regardless of n and there is no size cap at all.
 
 Weighted extension (DESIGN.md §3/§4): with ``(⊕, ⊙)`` the semiring whose
 ``add`` matches the semigroup ``op``, passing an ``(n, k)`` ``weights`` array
-turns each step into one extra ``(B, k)`` VMEM slice-load plus a per-lane
-semiring-⊙ before the tree-⊕ — the recurrence becomes
+turns each term into one extra weight-row read plus a per-lane semiring-⊙
+before the ⊕ fold — the recurrence becomes
 ``ST[i] = ⊕_j (ST[i-a_j] ⊙ w[i, j])``, which is the form every weighted zoo
 problem (edit distance, LCS, Viterbi, knapsack) linearizes into. The
-arg-emitting variant (``sdp_pipeline_pallas_with_args``) additionally stores
-the winning lane index next to each cost block: the int32 arg store rides the
-same per-step address vector the cost store already proved conflict-free, so
-Theorem 1's write-distinctness argument extends verbatim (DESIGN.md §5).
+arg-emitting variants additionally store the winning lane index next to each
+cost block with the same address vector as the cost store, so Theorem 1's
+write-distinctness argument extends verbatim (DESIGN.md §5).
 """
 from __future__ import annotations
 
@@ -39,169 +44,66 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.semiring import SEMIGROUP_TO_SEMIRING
+from repro.kernels.rows import (LANES, load_lanes, load_window, nvec,
+                                store_lanes, vmem_params)
 
 _OPS = {"min": jnp.minimum, "max": jnp.maximum, "add": jnp.add}
 #: strict "new term wins" predicates reproducing jnp.arg{min,max}'s
 #: first-occurrence tie-breaking when lanes are scanned in ascending order
 _BEATS = {"min": jnp.less, "max": jnp.greater}
-
-
-def _plan(offsets, n: int, block: int):
-    """Shared block geometry: (B, num_blocks, n_pad)."""
-    a1, ak = offsets[0], offsets[-1]
-    B = max(1, min(ak, block))
-    num_blocks = -(-(n - a1) // B)
-    return B, num_blocks, a1 + num_blocks * B
-
-
-def _make_kernel(offsets, op, B, num_blocks, weighted, with_args):
-    a1 = offsets[0]
-    combine = _OPS[op]
-    mul = SEMIGROUP_TO_SEMIRING[op].mul
-
-    def kernel(*refs):
-        refs = list(refs)
-        st_ref = refs.pop(0)
-        w_ref = refs.pop(0) if weighted else None
-        out_ref = refs.pop(0)
-        arg_ref = refs.pop(0) if with_args else None
-
-        out_ref[...] = st_ref[...]
-        if with_args:
-            arg_ref[...] = jnp.full_like(arg_ref[...], -1)
-
-        def body(b, _):
-            start = a1 + b * B
-            if weighted:
-                wrow = w_ref[pl.ds(start, B), :]          # one (B, k) load
-
-            def term(j):
-                t = out_ref[pl.ds(start - offsets[j], B)]
-                return mul(t, wrow[:, j]) if weighted else t
-
-            acc = term(0)
-            if with_args:
-                arg = jnp.zeros((B,), dtype=jnp.int32)
-            for j in range(1, len(offsets)):  # k unrolled static-offset slices
-                val = term(j)
-                if with_args:
-                    arg = jnp.where(_BEATS[op](val, acc), jnp.int32(j), arg)
-                acc = combine(acc, val)
-            out_ref[pl.ds(start, B)] = acc
-            if with_args:
-                arg_ref[pl.ds(start, B)] = arg
-            return 0
-
-        jax.lax.fori_loop(0, num_blocks, body, 0)
-
-    return kernel
-
-
-def _pad_inputs(init, weights, offsets, n, n_pad):
-    st0 = jnp.zeros((n_pad,), dtype=init.dtype).at[: offsets[0]].set(init)
-    ops = [st0]
-    if weights is not None:
-        ops.append(jnp.zeros((n_pad, len(offsets)),
-                             dtype=st0.dtype).at[:n].set(weights.astype(st0.dtype)))
-    return ops
-
-
-@functools.partial(jax.jit, static_argnames=("offsets", "op", "n", "block", "interpret"))
-def sdp_pipeline_pallas(init, offsets: tuple, op: str, n: int,
-                        block: int = 512, weights=None,
-                        interpret: bool = False):
-    """init: (a_1,) preset values; optional (n, k) semiring ``weights``.
-    Returns ST[0..n-1]."""
-    a1 = offsets[0]
-    if n <= a1:  # preset-only table: nothing to pipeline, clamp the presets
-        return init[:n]
-    B, num_blocks, n_pad = _plan(offsets, n, block)
-    kernel = _make_kernel(offsets, op, B, num_blocks,
-                          weighted=weights is not None, with_args=False)
-    out = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((n_pad,), init.dtype),
-        interpret=interpret,
-    )(*_pad_inputs(init, weights, offsets, n, n_pad))
-    return out[:n]
-
-
-@functools.partial(jax.jit, static_argnames=("offsets", "op", "n", "block", "interpret"))
-def sdp_pipeline_pallas_with_args(init, offsets: tuple, op: str, n: int,
-                                  block: int = 512, weights=None,
-                                  interpret: bool = False):
-    """``sdp_pipeline_pallas`` + the per-cell winning-lane index (preset cells
-    carry -1), matching ``core.sdp.solve_blocked_with_args`` exactly: lanes are
-    scanned in ascending order with a strict improve predicate, which is
-    jnp.arg{min,max}'s first-occurrence tie rule. Returns ``(st, args)``."""
-    if op not in _BEATS:
-        raise ValueError(f"argument tracking is undefined for op={op!r} "
-                         "(every lane contributes to the reduction)")
-    a1 = offsets[0]
-    if n <= a1:  # preset-only: clamped presets, every cell an init cell
-        return init[:n], jnp.full((n,), -1, dtype=jnp.int32)
-    B, num_blocks, n_pad = _plan(offsets, n, block)
-    kernel = _make_kernel(offsets, op, B, num_blocks,
-                          weighted=weights is not None, with_args=True)
-    out, args = pl.pallas_call(
-        kernel,
-        out_shape=(jax.ShapeDtypeStruct((n_pad,), init.dtype),
-                   jax.ShapeDtypeStruct((n_pad,), jnp.int32)),
-        interpret=interpret,
-    )(*_pad_inputs(init, weights, offsets, n, n_pad))
-    return out[:n], args[:n]
-
-
-# ---------------------------------------------------------------------------
-# Chunked HBM-streaming variant (DESIGN.md §4): the table never sits in VMEM.
-# The grid walks C-cell chunks sequentially; BlockSpec pipelining streams each
-# chunk's (C, k) weight tile HBM→VMEM (double-buffered, chunked_scan's idiom)
-# and streams the finished chunk back out, while a persistent (a_1 + C) VMEM
-# scratch window carries the inter-chunk boundary — the last a_1 finalized
-# cells, which is the recurrence's whole dependency horizon (a_1 = max offset).
-# VMEM high-water is O(a_1 + C·(k+3)) bytes regardless of n: no size cap.
-# ---------------------------------------------------------------------------
+#: default VMEM budget that sizes the chunked kernel's chunks
 DEFAULT_CHUNK_BUDGET = 8 << 20
 
 
-def _chunk_plan(offsets, n: int, block: int, chunk, budget):
-    """Chunk geometry: (B, C, nc). C is a multiple of the step block B so the
-    in-kernel block loop never straddles a chunk edge; sized from ``budget``
-    (≈ 4·(k+3) VMEM bytes per streamed cell: window + weight lanes + cost +
-    arg) unless ``chunk`` pins it explicitly."""
-    a1, ak = offsets[0], offsets[-1]
-    B = max(1, min(ak, block))
-    M = n - a1                       # cells to compute
-    mb = -(-M // B)                  # blocks needed overall
-    if chunk is not None:
-        cb = max(1, -(-chunk // B))
+def _plan(offsets, n: int, block: int, chunk=None, budget=None,
+          resident: bool = False):
+    """Kernel geometry ``(B, A, C, nc)``: cells per step, carried cells (whole
+    rows covering ``a_1``), cells per chunk (a multiple of 128, hence of B),
+    and chunk count. ``resident`` takes the whole table as one chunk;
+    otherwise C comes from ``chunk`` or from ``budget`` (≈ 4·(k+3) VMEM bytes
+    per streamed cell: window + weight lanes + cost + arg)."""
+    a1, ak = int(offsets[0]), int(offsets[-1])
+    B = 1 << (max(1, min(ak, block, LANES)).bit_length() - 1)
+    A = LANES * nvec(a1)
+    M = n - a1                                   # cells to compute
+    whole = LANES * nvec(M)
+    if resident:
+        C = whole
+    elif chunk is not None:
+        C = min(LANES * nvec(chunk), whole)
     else:
-        cap = max(B, (budget or DEFAULT_CHUNK_BUDGET) // (4 * (len(offsets) + 3)))
-        cb = max(1, cap // B)
-    C = min(cb, mb) * B
-    return B, C, -(-M // C)
+        cap = (budget or DEFAULT_CHUNK_BUDGET) // (4 * (len(offsets) + 3))
+        C = min(max(LANES, cap // LANES * LANES), whole)
+    return B, A, C, -(-M // C)
 
 
 def chunk_geometry(offsets, n: int, block: int = 512, chunk=None,
-                   budget=None) -> dict:
-    """The chunked kernel's window geometry as data, for the static
-    schedule-hazard verifier (DESIGN.md §10): the carried window prefix is
-    the last ``a_1`` computed cells (``carry = win[C : C + a_1]`` in the
-    kernel), the window holds carry + one chunk, and chunks are whole step
-    blocks. ``repro.dp.schedule.chunk_carry_invariants`` checks those
-    properties; deriving them from ``_chunk_plan`` itself keeps the checked
-    geometry honest against the real kernel."""
-    B, C, nc = _chunk_plan(tuple(int(a) for a in offsets), n, block, chunk,
-                           budget)
-    a1 = int(offsets[0])
-    return {"block": B, "chunk": C, "chunks": nc,
-            "carry": a1, "window": a1 + C}
+                   budget=None, resident: bool = False) -> dict:
+    """The kernel's window geometry as data, for the static schedule-hazard
+    verifier (DESIGN.md §10): the carried window prefix is the last
+    ``carry ≥ a_1`` computed cells, the window holds carry + one chunk, and
+    chunks are whole step blocks. ``repro.dp.schedule
+    .chunk_carry_invariants`` checks those properties; deriving them from
+    ``_plan`` itself keeps the checked geometry honest against the kernel."""
+    B, A, C, nc = _plan(tuple(int(a) for a in offsets), n, block, chunk,
+                        budget, resident)
+    return {"block": B, "chunk": C, "chunks": nc, "carry": A,
+            "window": A + C}
 
 
-def _make_chunked_kernel(offsets, op, B, C, weighted, with_args):
-    a1 = offsets[0]
+def resident_vmem_bytes(offsets, n: int, weighted: bool,
+                        block: int = 512) -> int:
+    """Working set of the one-chunk (VMEM-resident) kernel: the window, the
+    weight rows, and the cost and arg outputs, 4 bytes a cell."""
+    _, A, C, _ = _plan(tuple(offsets), n, block, resident=True)
+    k = len(offsets) if weighted else 0
+    return 4 * (A + C + LANES + C * (k + 2))
+
+
+def _make_kernel(offsets, op, B, A, C, weighted, with_args):
     combine = _OPS[op]
     mul = SEMIGROUP_TO_SEMIRING[op].mul
+    Ar, Cr = A // LANES, C // LANES
 
     def kernel(*refs):
         refs = list(refs)
@@ -212,75 +114,114 @@ def _make_chunked_kernel(offsets, op, B, C, weighted, with_args):
         win_ref = refs.pop(0)
 
         @pl.when(pl.program_id(0) == 0)
-        def _seed():  # window cells [0, a1) = the preset table
-            win_ref[pl.ds(0, a1)] = init_ref[...]
+        def _seed():  # window rows [0, Ar): the preset table, right-aligned
+            win_ref[0:Ar, :] = init_ref[...]
 
         def body(b, _):
-            s = a1 + b * B                     # window-local block start
-            if weighted:
-                wrow = w_ref[pl.ds(b * B, B), :]
+            p = b * B                          # chunk-local block start
+            s = A + p                          # window position
 
             def term(j):
-                t = win_ref[pl.ds(s - offsets[j], B)]
-                return mul(t, wrow[:, j]) if weighted else t
+                t = load_window(win_ref, s - offsets[j], 1)
+                return mul(t, load_lanes(w_ref.at[j], p)) if weighted else t
 
             acc = term(0)
             if with_args:
-                arg = jnp.zeros((B,), dtype=jnp.int32)
-            for j in range(1, len(offsets)):
+                arg = jnp.zeros((1, LANES), dtype=jnp.int32)
+            for j in range(1, len(offsets)):  # k unrolled shifted reads
                 val = term(j)
                 if with_args:
                     arg = jnp.where(_BEATS[op](val, acc), jnp.int32(j), arg)
                 acc = combine(acc, val)
-            win_ref[pl.ds(s, B)] = acc
+            store_lanes(win_ref, s, acc, B)
             if with_args:
-                arg_ref[pl.ds(b * B, B)] = arg
+                store_lanes(arg_ref, p, arg, B)
             return 0
 
         jax.lax.fori_loop(0, C // B, body, 0)
-        out_ref[...] = win_ref[pl.ds(a1, C)]
-        # Slide the window: the next chunk's first cell depends on the last a1
-        # cells just finalized. Materialize before writing — when C < a1 the
-        # source and destination ranges overlap.
-        carry = win_ref[pl.ds(C, a1)]
-        win_ref[pl.ds(0, a1)] = carry
+        out_ref[...] = win_ref[Ar:Ar + Cr, :]
+        # Slide the window: the next chunk's first cell depends on the last
+        # A cells just finalized. Materialize before writing — when C < A
+        # the source and destination rows overlap.
+        carry = win_ref[Cr:Cr + Ar, :]
+        win_ref[0:Ar, :] = carry
 
     return kernel
 
 
-def _chunked_call(init, offsets, op, n, block, chunk, budget, weights,
-                  with_args, interpret):
-    a1 = offsets[0]
-    B, C, nc = _chunk_plan(offsets, n, block, chunk, budget)
-    k = len(offsets)
-    kernel = _make_chunked_kernel(offsets, op, B, C,
-                                  weighted=weights is not None,
-                                  with_args=with_args)
-    operands = [init]
-    in_specs = [pl.BlockSpec((a1,), lambda c: (0,))]
+def _call(init, offsets, op, n, geom, weights, with_args, interpret):
+    B, A, C, nc = geom
+    a1, k = offsets[0], len(offsets)
+    Ar, Cr = A // LANES, C // LANES
+    dt = init.dtype
+    operands = [jnp.zeros((A,), dt).at[A - a1:].set(init).reshape(Ar, LANES)]
+    in_specs = [pl.BlockSpec((Ar, LANES), lambda c: (0, 0))]
+    nbytes = 4 * (2 * A + A + C + LANES)
     if weights is not None:
-        wpad = jnp.zeros((nc * C, k), dtype=init.dtype)
-        operands.append(wpad.at[: n - a1].set(weights[a1:n].astype(init.dtype)))
-        in_specs.append(pl.BlockSpec((C, k), lambda c: (c, 0)))
-    out_shape = [jax.ShapeDtypeStruct((nc * C,), init.dtype)]
-    out_specs = [pl.BlockSpec((C,), lambda c: (c,))]
+        wt = jnp.zeros((k, nc * C), dt).at[:, : n - a1].set(
+            weights[a1:n].astype(dt).T)
+        operands.append(wt.reshape(k, nc, Cr, LANES))
+        in_specs.append(pl.BlockSpec((k, None, Cr, LANES),
+                                     lambda c: (0, c, 0, 0)))
+        nbytes += 4 * 2 * k * C
+    out_shape = [jax.ShapeDtypeStruct((nc, Cr, LANES), dt)]
+    out_specs = [pl.BlockSpec((None, Cr, LANES), lambda c: (c, 0, 0))]
     if with_args:
-        out_shape.append(jax.ShapeDtypeStruct((nc * C,), jnp.int32))
-        out_specs.append(pl.BlockSpec((C,), lambda c: (c,)))
+        out_shape.append(jax.ShapeDtypeStruct((nc, Cr, LANES), jnp.int32))
+        out_specs.append(pl.BlockSpec((None, Cr, LANES), lambda c: (c, 0, 0)))
+    nbytes += 4 * 2 * C * len(out_shape)
     out = pl.pallas_call(
-        kernel,
+        _make_kernel(offsets, op, B, A, C, weighted=weights is not None,
+                     with_args=with_args),
         grid=(nc,),
         in_specs=in_specs,
-        out_specs=out_specs if with_args else out_specs[0],
-        out_shape=out_shape if with_args else out_shape[0],
-        scratch_shapes=[pltpu.VMEM((a1 + C,), init.dtype)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[pltpu.VMEM((Ar + Cr + 1, LANES), dt)],
+        compiler_params=vmem_params(nbytes),
         interpret=interpret,
     )(*operands)
+    st = jnp.concatenate([init, out[0].reshape(-1)])[:n]
     if not with_args:
-        return jnp.concatenate([init, out])[:n]
-    st = jnp.concatenate([init, out[0]])[:n]
-    args = jnp.concatenate([jnp.full((a1,), -1, dtype=jnp.int32), out[1]])[:n]
+        return st
+    args = jnp.concatenate([jnp.full((a1,), -1, dtype=jnp.int32),
+                            out[1].reshape(-1)])[:n]
     return st, args
+
+
+def _check_args_op(op):
+    if op not in _BEATS:
+        raise ValueError(f"argument tracking is undefined for op={op!r} "
+                         "(every lane contributes to the reduction)")
+
+
+@functools.partial(jax.jit, static_argnames=("offsets", "op", "n", "block", "interpret"))
+def sdp_pipeline_pallas(init, offsets: tuple, op: str, n: int,
+                        block: int = 512, weights=None,
+                        interpret: bool = False):
+    """init: (a_1,) preset values; optional (n, k) semiring ``weights``.
+    Returns ST[0..n-1] from the VMEM-resident (one-chunk) kernel."""
+    if n <= offsets[0]:  # preset-only table: nothing to pipeline, clamp
+        return init[:n]
+    return _call(init, offsets, op, n,
+                 _plan(offsets, n, block, resident=True), weights,
+                 with_args=False, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("offsets", "op", "n", "block", "interpret"))
+def sdp_pipeline_pallas_with_args(init, offsets: tuple, op: str, n: int,
+                                  block: int = 512, weights=None,
+                                  interpret: bool = False):
+    """``sdp_pipeline_pallas`` + the per-cell winning-lane index (preset cells
+    carry -1), matching ``core.sdp.solve_blocked_with_args`` exactly: lanes are
+    scanned in ascending order with a strict improve predicate, which is
+    jnp.arg{min,max}'s first-occurrence tie rule. Returns ``(st, args)``."""
+    _check_args_op(op)
+    if n <= offsets[0]:  # preset-only: clamped presets, every cell an init cell
+        return init[:n], jnp.full((n,), -1, dtype=jnp.int32)
+    return _call(init, offsets, op, n,
+                 _plan(offsets, n, block, resident=True), weights,
+                 with_args=True, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("offsets", "op", "n", "block",
@@ -290,13 +231,13 @@ def sdp_chunked_pallas(init, offsets: tuple, op: str, n: int,
                        budget: int | None = None, weights=None,
                        interpret: bool = False):
     """HBM-streaming ``sdp_pipeline_pallas``: same recurrence, but the table
-    streams through a ``(a_1 + C)`` VMEM window instead of residing whole in
+    streams through an ``(A + C)`` VMEM window instead of residing whole in
     VMEM — any n fits. Returns ST[0..n-1]."""
-    a1 = offsets[0]
-    if n <= a1:  # preset-only table: nothing to pipeline, clamp the presets
+    if n <= offsets[0]:  # preset-only table: nothing to pipeline, clamp
         return init[:n]
-    return _chunked_call(init, offsets, op, n, block, chunk, budget, weights,
-                         with_args=False, interpret=interpret)
+    return _call(init, offsets, op, n,
+                 _plan(offsets, n, block, chunk, budget), weights,
+                 with_args=False, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("offsets", "op", "n", "block",
@@ -308,11 +249,9 @@ def sdp_chunked_pallas_with_args(init, offsets: tuple, op: str, n: int,
     """``sdp_chunked_pallas`` + per-cell winning-lane indices (preset cells
     carry -1), same ascending-lane strict-improve tie rule as
     ``solve_blocked_with_args``. Returns ``(st, args)``."""
-    if op not in _BEATS:
-        raise ValueError(f"argument tracking is undefined for op={op!r} "
-                         "(every lane contributes to the reduction)")
-    a1 = offsets[0]
-    if n <= a1:  # preset-only: clamped presets, every cell an init cell
+    _check_args_op(op)
+    if n <= offsets[0]:  # preset-only: clamped presets, every cell an init cell
         return init[:n], jnp.full((n,), -1, dtype=jnp.int32)
-    return _chunked_call(init, offsets, op, n, block, chunk, budget, weights,
-                         with_args=True, interpret=interpret)
+    return _call(init, offsets, op, n,
+                 _plan(offsets, n, block, chunk, budget), weights,
+                 with_args=True, interpret=interpret)

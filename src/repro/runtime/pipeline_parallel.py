@@ -20,7 +20,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.core.schedule import SkewedSchedule
 
@@ -61,8 +60,8 @@ def pipeline_apply(stage_fn: Callable, stacked_params, x_micro, mesh: Mesh,
         buf, outs = jax.lax.fori_loop(0, sched.num_steps, step, (buf, outs))
         return jax.lax.psum(outs, axis)                     # zeros elsewhere
 
-    fn = shard_map(inner, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(inner, mesh=mesh, in_specs=(P(axis), P()),
+                       out_specs=P(), check_vma=False)
     return fn(stacked_params, x_micro)
 
 

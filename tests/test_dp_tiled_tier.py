@@ -96,23 +96,22 @@ def test_vmem_budget_resizes_kernel_eligibility(monkeypatch):
 # ---------------------------------------------------------------------------
 # triangular tiled kernel: bit-equality property sweep
 # ---------------------------------------------------------------------------
-TRI_CASES = [(2, (1, 1)), (3, (2, 3)), (5, (4, 2)), (8, None), (13, (7, 5)),
-             (21, (2, 2))]
+TRI_CASES = [(2, 1), (3, 3), (5, 2), (8, None), (13, 5), (21, 2), (21, 20)]
 
 
 def test_tiled_triangular_bit_equal_sweep(interpret_mode):
     """Tables AND args of the tiled kernel equal the jnp wavefront solver
-    bit-for-bit across ragged n × tile-shape combos (tiles that divide the
-    band, tiles that straddle it, single-cell tiles)."""
-    for n, tiles in TRI_CASES:
-        rng = _rng(f"tri/{n}/{tiles}")
+    bit-for-bit across ragged n × split-tile combos (tiles that divide a
+    diagonal's splits, tiles that straddle them, single-split tiles)."""
+    for n, tile_e in TRI_CASES:
+        rng = _rng(f"tri/{n}/{tile_e}")
         cells = n * (n + 1) // 2
         wtab = rng.standard_normal((cells, max(n - 1, 1))).astype(np.float32)
         ref_st, ref_ar = solve_wavefront_tab_with_args(wtab, n)
-        kw = {} if tiles is None else {"tile_t": tiles[0], "tile_e": tiles[1]}
-        st, ar = mcm_tiled_pallas_with_args(wtab, n, interpret=True, **kw)
-        assert np.array_equal(np.asarray(ref_st), np.asarray(st)), (n, tiles)
-        assert np.array_equal(np.asarray(ref_ar), np.asarray(ar)), (n, tiles)
+        st, ar = mcm_tiled_pallas_with_args(wtab, n, interpret=True,
+                                            tile_e=tile_e)
+        assert np.array_equal(np.asarray(ref_st), np.asarray(st)), (n, tile_e)
+        assert np.array_equal(np.asarray(ref_ar), np.asarray(ar)), (n, tile_e)
 
 
 def test_tiled_triangular_beyond_budget(interpret_mode, tiny_budget):

@@ -16,6 +16,36 @@ def random_dims(n, lo=1, hi=30, seed=None):
     return r.integers(lo, hi, size=n + 1).astype(np.float64)
 
 
+def _mcm_reference_loop(dims):
+    """The triple loop the vectorized oracle replaced (same float64 ops)."""
+    p = np.asarray(dims, dtype=np.float64)
+    n = len(p) - 1
+    m = np.zeros((n, n))
+    split = np.full((n, n), -1, dtype=np.int64)
+    for d in range(1, n):
+        for i in range(n - d):
+            j = i + d
+            best, bs = np.inf, -1
+            for s in range(i, j):
+                c = m[i, s] + m[s + 1, j] + p[i] * p[s + 1] * p[j + 1]
+                if c < best:
+                    best, bs = c, s
+            m[i, j] = best
+            split[i, j] = bs
+    return m, split
+
+
+@pytest.mark.parametrize("n,hi", [(1, 30), (2, 30), (17, 30), (40, 3)])
+def test_mcm_reference_matches_loop(n, hi):
+    """Vectorized oracle == the loop, tables and first-occurrence splits
+    (hi=3 forces ties)."""
+    dims = random_dims(n, hi=hi, seed=n)
+    m, split = mcm.mcm_reference(dims)
+    m_loop, split_loop = _mcm_reference_loop(dims)
+    assert np.array_equal(m, m_loop)
+    assert np.array_equal(split, split_loop)
+
+
 # ---------------------------------------------------------------------------
 # Linearization
 # ---------------------------------------------------------------------------
