@@ -12,8 +12,8 @@ Prints ``service,<devices>,<requests>,<req_per_s>,<p50_ms>,<p99_ms>,
 <cache_hit_rate>,<ok>`` CSV lines, writes ``BENCH_dp_service.json`` and a
 compact telemetry *summary* (counters + per-histogram count/p50/p99, a few
 hundred lines) to ``TELEMETRY_dp_service_summary.json`` — the file that is
-committed run-over-run. The full snapshot (every span, every routing-audit
-row; tens of thousands of lines) still goes to ``TELEMETRY_dp_service.json``
+committed run-over-run. The full snapshot (every span; tens of thousands
+of lines) still goes to ``TELEMETRY_dp_service.json``
 but is a CI artifact only, never committed.
 
 The append-heavy *streaming* leg (DESIGN.md §11) drives one growing
@@ -133,7 +133,6 @@ def _measure(n_requests: int, seed: int = 0, telemetry_mode: str = "spans",
     # the warm leg's telemetry is not part of the measurement
     telemetry.REGISTRY.reset()
     telemetry.clear_spans()
-    telemetry.clear_audit()
 
     svc = dp.DPService(max_batch=32, feedback=feedback)
     submit_t = {}
@@ -327,9 +326,9 @@ def _csv_streaming(row: dict) -> None:
 
 def _telemetry_summary(telemetry) -> dict:
     """Compact, committable digest of the registry: counters/gauges plus
-    count/p50/p99 per histogram — no span bodies, no audit rows (those stay
-    in the full snapshot, which is a CI artifact only)."""
-    snap = telemetry.snapshot(spans_limit=1, audit_limit=1)
+    count/p50/p99 per histogram — no span bodies (those stay in the full
+    snapshot, which is a CI artifact only)."""
+    snap = telemetry.snapshot(spans_limit=1)
     return {
         "mode": snap["mode"],
         "counters": snap["counters"],
@@ -352,7 +351,7 @@ def run(out_path: str = "BENCH_dp_service.json",
     legs = [_measure(n_requests)]
     _csv(legs[0])
     if telemetry_out_path:
-        # the CI artifact: full spans/metrics/audit state of the leg
+        # the CI artifact: full spans/metrics state of the leg
         print(f"# wrote {telemetry.save_snapshot(telemetry_out_path)}")
     if telemetry_summary_path:
         # the committed file: small enough to diff run-over-run

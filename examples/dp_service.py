@@ -4,7 +4,7 @@ Mixed-problem traffic through submit/poll handles — priorities, deadlines,
 the content-digest answer cache, intra-drain dedup, and (with more than one
 visible device) sharded bucket drains. Runs with telemetry in ``spans``
 mode (DESIGN.md §8), with a request's timestamped span, the per-phase
-latency breakdown, the routing audit, and a Prometheus excerpt.
+latency breakdown, and a Prometheus excerpt.
 
 The tour ends with a streaming session (DESIGN.md §11): one alignment
 instance grown a few columns at a time through ``open_session/append``,
@@ -113,10 +113,7 @@ def main() -> None:
             print(f"  {name:28s} n={h.count:4d} p50={h.quantile(0.5):8.3f} "
                   f"p99={h.quantile(0.99):8.3f} ms")
 
-    # 3. the routing audit records what every decision saw; 4. exporters
-    decisions = rep["decisions"]
-    print(f"\nrouting audit: {len(decisions)} decisions recorded "
-          f"(last: {decisions[-1]['kind']} -> {decisions[-1]['chosen']})")
+    # 3. exporters
     prom = telemetry.to_prometheus().splitlines()
     print(f"prometheus export: {len(prom)} lines, e.g.")
     for line in prom[:4]:

@@ -31,8 +31,8 @@ Layers (DESIGN.md §3, §5):
                 deadlines/priorities, content-digest answer cache,
                 streaming sessions (open_session/append/close_session),
                 the continuous scheduling loop (DESIGN.md §7, §11)
-  telemetry   — request spans, metrics registry, routing audit, exporters
-                (REPRO_TELEMETRY={off,basic,spans,profile}; DESIGN.md §8)
+  telemetry   — request spans, metrics registry, profiler spans, exporters
+                (REPRO_TELEMETRY={off,basic,spans}; DESIGN.md §8)
 
 Quickstart::
 

@@ -355,49 +355,16 @@ def _rank_by(pool: list, resolve) -> list:
     return [d[3] for d in decorated]
 
 
-def _audit_decision(kind: str, spec: Spec, regime, pool: list,
-                    scores: dict, ranked: list) -> None:
-    """File one rank decision into the telemetry routing audit: every
-    candidate with its measured ms (None = unmeasured in this regime) and
-    analytical cost, plus the winner. No-op unless audit is enabled, so
-    routing pays nothing by default."""
-    if not _telemetry.audit_enabled() or not ranked:
-        return
-    rows = []
-    for b in pool:
-        try:
-            analytic = float(b.cost(spec))
-        except Exception:
-            analytic = float("inf")
-        ms = scores.get(b.name)
-        rows.append({"backend": b.name,
-                     "measured_ms": None if ms is None else round(ms, 6),
-                     "analytical_cost": round(analytic, 3)})
-    _telemetry.record_route_decision(
-        kind, spec.shape_key(), regime, rows, ranked[0].name)
-
-
 def rank(spec: Spec, cands: Sequence, suffix: tuple = ()) -> list:
     """Two-tier ordering of candidate backends: tier 0 = measured cost,
     tier 1 = unmeasured in analytical order (the model as prior and
     tiebreak). ``suffix`` selects the measurement regime (see
-    :func:`measured_ms`). Each call files a routing-audit entry when
-    telemetry runs in ``spans`` mode."""
+    :func:`measured_ms`)."""
     t = get_table()
-    scores: dict = {}
     if not len(t):
-        ranked = list(cands)
-        _audit_decision("rank", spec, suffix, ranked, scores, ranked)
-        return ranked
-
-    def resolve(i, b):
-        ms = measured_ms(b, spec, table=t, suffix=suffix)
-        scores[b.name] = ms
-        return ms
-
-    ranked = _rank_by(list(cands), resolve)
-    _audit_decision("rank", spec, suffix, ranked, scores, ranked)
-    return ranked
+        return list(cands)
+    return _rank_by(list(cands), lambda i, b: measured_ms(
+        b, spec, table=t, suffix=suffix))
 
 
 def rank_batch(spec: Spec, batchable: Sequence, loop_only: Sequence,
@@ -417,9 +384,7 @@ def rank_batch(spec: Spec, batchable: Sequence, loop_only: Sequence,
     single-device batch regime."""
     t = get_table()
     pool = list(batchable) + list(loop_only)
-    scores: dict = {}
     if not len(t):
-        _audit_decision("rank_batch", spec, batch_suffix, pool, scores, pool)
         return pool
     loop_suffix = batch_suffix if loop_suffix is None else loop_suffix
 
@@ -428,14 +393,10 @@ def rank_batch(spec: Spec, batchable: Sequence, loop_only: Sequence,
             ms = measured_ms(b, spec, table=t, suffix=batch_suffix)
             if ms is None:
                 ms = measured_ms(b, spec, table=t)
-        else:
-            ms = measured_ms(b, spec, table=t, suffix=loop_suffix)
-        scores[b.name] = ms
-        return ms
+            return ms
+        return measured_ms(b, spec, table=t, suffix=loop_suffix)
 
-    ranked = _rank_by(pool, resolve)
-    _audit_decision("rank_batch", spec, batch_suffix, ranked, scores, ranked)
-    return ranked
+    return _rank_by(pool, resolve)
 
 
 # ---------------------------------------------------------------------------
@@ -495,20 +456,14 @@ def calibrate(problems: Optional[Sequence[str]] = None,
 # ---------------------------------------------------------------------------
 # Reporting
 # ---------------------------------------------------------------------------
-def routing_report(table: Optional[CalibrationTable] = None,
-                   decisions_limit: int = 256) -> dict:
+def routing_report(table: Optional[CalibrationTable] = None) -> dict:
     """Measured-vs-analytical dispatch audit over every calibrated shape on
     the current JAX backend: which route each policy picks, whether they
     agree, and the *analytical regret* — measured ms of the analytical pick
     over measured ms of the true fastest (1.0 = the model was right).
     Rows are grouped per (shape, measurement regime); only rows where at
     least two routes were measured enter the agree/regret statistics —
-    a single-backend row can't disagree with anything.
-
-    ``decisions`` holds the most recent per-decision telemetry audit
-    entries (``spans`` mode) — each live ``rank``/``rank_batch``/drain
-    resolution with its candidates' measured-vs-analytical scores, regime
-    key, and chosen backend; empty below ``spans`` mode."""
+    a single-backend row can't disagree with anything."""
     t = table if table is not None else get_table()
     jb = _jax_backend()
     by_shape: Dict[tuple, Dict[str, Entry]] = {}
@@ -552,5 +507,4 @@ def routing_report(table: Optional[CalibrationTable] = None,
         "median_analytical_regret":
             float(np.median(regrets)) if regrets else 1.0,
         "max_analytical_regret": float(max(regrets)) if regrets else 1.0,
-        "decisions": _telemetry.routing_audit(limit=decisions_limit),
     }

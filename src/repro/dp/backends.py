@@ -23,6 +23,7 @@ from typing import Callable, Optional
 
 import numpy as np
 
+from repro.dp import telemetry as _telemetry
 from repro.dp.problem import (LinearSpec, Spec, TriangularSpec,
                               family_class)
 
@@ -56,8 +57,6 @@ def log_trace(key) -> None:
         TRACE_LOG.append(key)
         if len(TRACE_LOG) > TRACE_LOG_MAX:
             del TRACE_LOG[: len(TRACE_LOG) - TRACE_LOG_MAX]
-    from repro.dp import telemetry as _telemetry
-
     _telemetry.count("dp_backend_traces_total")
 
 
@@ -81,6 +80,44 @@ def stack_bucket(arrays, sharding=None):
     if sharding is None:
         return jnp.stack([jnp.asarray(a) for a in arrays])
     return sharding.place(np.stack([np.asarray(a) for a in arrays]))
+
+
+def stack_slots(specs, lane_arrays: Callable, sharding=None) -> tuple:
+    """The drain's host→device copy: ``lane_arrays(spec)`` gives each
+    lane's host arrays, one per program argument, and each argument's
+    lanes are stacked with :func:`stack_bucket`. Runs under the profiler
+    span ``dp.stack``, with the bytes and the number of host arrays it
+    sends."""
+    with _telemetry.trace_span("dp.stack") as span:
+        lanes = [lane_arrays(s) for s in specs]
+        if _telemetry.tracing():
+            span.set_metadata(
+                h2d_bytes=sum(a.nbytes for arrs in lanes for a in arrs),
+                arrays=sum(len(arrs) for arrs in lanes))
+        return tuple(stack_bucket(slot, sharding) for slot in zip(*lanes))
+
+
+def launch(program: Callable, *stacked):
+    """Call a drain's jitted ``program`` under the profiler span
+    ``dp.launch``. Dispatch is asynchronous, so the span ends before the
+    device does; a trace and compile lands inside it, counted as the
+    span's ``compiles`` (the :data:`TRACE_COUNT` delta)."""
+    before = TRACE_COUNT
+    with _telemetry.trace_span("dp.launch") as span:
+        out = program(*stacked)
+        span.set_metadata(compiles=TRACE_COUNT - before)
+    return out
+
+
+def fetch(*outs) -> list:
+    """Copy a drain's device outputs to the host — the drain's sync point,
+    so it waits for the device — under the profiler span ``dp.fetch`` with
+    the bytes it copies."""
+    with _telemetry.trace_span("dp.fetch") as span:
+        host = [np.asarray(o) for o in outs]
+        if _telemetry.tracing():
+            span.set_metadata(d2h_bytes=sum(h.nbytes for h in host))
+    return host
 
 
 def lru_put(cache: "OrderedDict", key, value, max_entries: int):
@@ -269,19 +306,21 @@ def linear_backend(name: str, jax_fn: Callable, cost: Callable,
             return sharding.wrap(call)
 
         cached = lru_cached(_BATCH_CACHE, key, build, _BATCH_CACHE_MAX)
-        inits = stack_bucket([s.init for s in specs], sharding)
         if spec0.weights is None:
-            return cached(inits)
-        return cached(inits, stack_bucket([s.weights for s in specs],
-                                          sharding))
+            stacked = stack_slots(specs, lambda s: (s.init,), sharding)
+        else:
+            stacked = stack_slots(specs, lambda s: (s.init, s.weights),
+                                  sharding)
+        return launch(cached, *stacked)
 
     def _batch_key(specs, sharding) -> tuple:
         shard_tag = sharding.cache_suffix() if sharding is not None else ()
         return (name, specs[0].shape_key()) + tag() + shard_tag
 
     def batch_run(specs, sharding=None) -> list:
-        return list(np.asarray(_batch(
-            jax_fn, specs, _batch_key(specs, sharding), sharding)))
+        table, = fetch(_batch(jax_fn, specs, _batch_key(specs, sharding),
+                              sharding))
+        return list(table)
 
     run_with_args = batch_run_with_args = None
     if jax_arg_fn is not None:
@@ -290,10 +329,10 @@ def linear_backend(name: str, jax_fn: Callable, cost: Callable,
             return np.asarray(st), np.asarray(args)
 
         def batch_run_with_args(specs, sharding=None):
-            sts, argss = _batch(jax_arg_fn, specs,
-                                _batch_key(specs, sharding) + ("args",),
-                                sharding)
-            return list(np.asarray(sts)), list(np.asarray(argss))
+            sts, argss = fetch(*_batch(
+                jax_arg_fn, specs, _batch_key(specs, sharding) + ("args",),
+                sharding))
+            return list(sts), list(argss)
 
     return Backend(name=name, geometry="linear", run=run, cost=cost,
                    supports=supports or (lambda s: True),
@@ -339,16 +378,18 @@ def triangular_tab_backend(name: str, jax_fn: Callable, cost: Callable,
                 return jax.jit(call)
             return sharding.wrap(call)
 
-        wtabs = stack_bucket([s.weights for s in specs], sharding)
-        return lru_cached(_BATCH_CACHE, key, build, _BATCH_CACHE_MAX)(wtabs)
+        cached = lru_cached(_BATCH_CACHE, key, build, _BATCH_CACHE_MAX)
+        return launch(cached, *stack_slots(specs, lambda s: (s.weights,),
+                                           sharding))
 
     def _batch_key(specs, sharding) -> tuple:
         shard_tag = sharding.cache_suffix() if sharding is not None else ()
         return (name, specs[0].shape_key()) + tag() + shard_tag
 
     def batch_run(specs, sharding=None) -> list:
-        return list(np.asarray(_batch(
-            jax_fn, specs, _batch_key(specs, sharding), sharding)))
+        table, = fetch(_batch(jax_fn, specs, _batch_key(specs, sharding),
+                              sharding))
+        return list(table)
 
     run_with_args = batch_run_with_args = None
     if jax_arg_fn is not None:
@@ -357,10 +398,10 @@ def triangular_tab_backend(name: str, jax_fn: Callable, cost: Callable,
             return np.asarray(st), np.asarray(args)
 
         def batch_run_with_args(specs, sharding=None):
-            sts, argss = _batch(jax_arg_fn, specs,
-                                _batch_key(specs, sharding) + ("args",),
-                                sharding)
-            return list(np.asarray(sts)), list(np.asarray(argss))
+            sts, argss = fetch(*_batch(
+                jax_arg_fn, specs, _batch_key(specs, sharding) + ("args",),
+                sharding))
+            return list(sts), list(argss)
 
     run_fused = batch_run_fused = None
     if jax_fused_fn is not None:
@@ -378,9 +419,9 @@ def triangular_tab_backend(name: str, jax_fn: Callable, cost: Callable,
             sts, argss, (ii, dd, ee) = _batch(
                 jax_fused_fn, specs,
                 _batch_key(specs, sharding) + ("fused",), sharding)
-            nodes = np.stack([np.asarray(ii), np.asarray(dd),
-                              np.asarray(ee)], axis=2)
-            return (list(np.asarray(sts)), list(np.asarray(argss)),
+            sts, argss, ii, dd, ee = fetch(sts, argss, ii, dd, ee)
+            nodes = np.stack([ii, dd, ee], axis=2)
+            return (list(sts), list(argss),
                     [TriangularPath(nodes=nodes[b].astype(np.int64))
                      for b in range(len(specs))])
 
@@ -422,7 +463,6 @@ def grid_backend(name: str, jax_fn: Callable, cost: Callable,
     def _batch(fn, specs, key, sharding=None):
         spec0 = specs[0]
         meta = spec0.static_meta()
-        slots = list(zip(*(s.device_arrays() for s in specs)))
 
         def build():
             def call(*stacked):
@@ -434,15 +474,17 @@ def grid_backend(name: str, jax_fn: Callable, cost: Callable,
             return sharding.wrap(call)
 
         cached = lru_cached(_BATCH_CACHE, key, build, _BATCH_CACHE_MAX)
-        return cached(*(stack_bucket(slot, sharding) for slot in slots))
+        return launch(cached, *stack_slots(specs, lambda s: s.device_arrays(),
+                                           sharding))
 
     def _batch_key(specs, sharding) -> tuple:
         shard_tag = sharding.cache_suffix() if sharding is not None else ()
         return (name, specs[0].shape_key()) + tag() + shard_tag
 
     def batch_run(specs, sharding=None) -> list:
-        return list(np.asarray(_batch(
-            jax_fn, specs, _batch_key(specs, sharding), sharding)))
+        table, = fetch(_batch(jax_fn, specs, _batch_key(specs, sharding),
+                              sharding))
+        return list(table)
 
     run_with_args = batch_run_with_args = None
     if jax_arg_fn is not None:
@@ -452,10 +494,10 @@ def grid_backend(name: str, jax_fn: Callable, cost: Callable,
             return np.asarray(st), np.asarray(args)
 
         def batch_run_with_args(specs, sharding=None):
-            sts, argss = _batch(jax_arg_fn, specs,
-                                _batch_key(specs, sharding) + ("args",),
-                                sharding)
-            return list(np.asarray(sts)), list(np.asarray(argss))
+            sts, argss = fetch(*_batch(
+                jax_arg_fn, specs, _batch_key(specs, sharding) + ("args",),
+                sharding))
+            return list(sts), list(argss)
 
     return Backend(name=name, geometry="grid", run=run, cost=cost,
                    supports=supports or (lambda s: True),

@@ -309,16 +309,11 @@ class DPEngine:
         uniq = [batch[i] for i in uniq_idx.values()]
         obs_key = uniq[0].spec.shape_key() + _routing.EXTEND_SUFFIX
         routes = [self._extend_route(r, backend) for r in uniq]
-        if _telemetry.audit_enabled():
-            _telemetry.record_route_decision(
-                "extend_drain", uniq[0].spec.shape_key(),
-                _routing.EXTEND_SUFFIX, [], routes[0][0].name,
-                bucket=repr(key), batch_size=len(batch), unique=len(uniq),
-                affine=any(a for _, a in routes),
-                override=backend is not None)
         tables, answers, lane_cold = [], [], []
         with _telemetry.drain_scope(key, routes[0][0].name, len(batch),
-                                    len(uniq)) as drain_rep:
+                                    len(uniq)) as drain_rep, \
+                _telemetry.trace_span("dp.drain", lanes=len(batch),
+                                      unique=len(uniq)) as drain_span:
             extend_ms = 0.0
             for r, (chosen, affine) in zip(uniq, routes):
                 tok = r.resume
@@ -327,7 +322,9 @@ class DPEngine:
                 ext = chosen.run_extend(r.spec, tok.old_len, tok.state())
                 table = r.spec.stitch_extension(tok.prefix_spec,
                                                 tok.prefix_table, ext)
-                lane_ms = (time.perf_counter() - t0) * 1e3
+                t1 = time.perf_counter()
+                _telemetry.add_phase("extend", t0, t1)
+                lane_ms = (t1 - t0) * 1e3
                 extend_ms += lane_ms
                 # same freezing rule as batched drains: dedup fan-out and
                 # the caches share this exact array
@@ -350,20 +347,21 @@ class DPEngine:
                         prob, r.spec, table, args, "host"))
                 else:
                     answers.append(None)
-            _telemetry.add_phase("extend", extend_ms)
+            drain_span.set_metadata(cold=int(any(lane_cold)))
             if drain_rep is not None:
                 drain_rep.cold = any(lane_cold)
         self.last_drain = drain_rep
         responses = []
-        for i, r in enumerate(batch):
-            j = lane_of[r.digest]
-            responses.append(DPResponse(
-                rid=r.rid, problem=r.problem,
-                answer=prob.extract(tables[j], r.spec),
-                backend=routes[j][0].name, batch_size=len(batch),
-                solution=answers[j], deduped=uniq_idx[r.digest] != i,
-                table=tables[j] if r.keep_table else None,
-                extended=True, affine=routes[j][1]))
+        with _telemetry.trace_span("dp.extract"):
+            for i, r in enumerate(batch):
+                j = lane_of[r.digest]
+                responses.append(DPResponse(
+                    rid=r.rid, problem=r.problem,
+                    answer=prob.extract(tables[j], r.spec),
+                    backend=routes[j][0].name, batch_size=len(batch),
+                    solution=answers[j], deduped=uniq_idx[r.digest] != i,
+                    table=tables[j] if r.keep_table else None,
+                    extended=True, affine=routes[j][1]))
         if rest:
             self._buckets[key] = rest
         else:
@@ -426,22 +424,18 @@ class DPEngine:
 
         obs_key = specs[0].shape_key() + self._obs_suffix(chosen, specs[0],
                                                           reconstruct)
-        if _telemetry.audit_enabled():
-            _telemetry.record_route_decision(
-                "drain", specs[0].shape_key(),
-                self._obs_suffix(chosen, specs[0], reconstruct), [],
-                chosen.name, bucket=repr(key), batch_size=len(batch),
-                unique=len(uniq_specs), explored=explored,
-                override=backend is not None)
         warm_key = (chosen.name, obs_key, len(uniq_specs))
         with _telemetry.drain_scope(key, chosen.name, len(batch),
-                                    len(uniq_specs)) as drain_rep:
+                                    len(uniq_specs)) as drain_rep, \
+                _telemetry.trace_span("dp.drain", lanes=len(batch),
+                                      unique=len(uniq_specs)) as drain_span:
             traces_before = _backends.TRACE_COUNT
             t0 = time.perf_counter()
             tables, argss, source, paths = self._run_bucket(
                 chosen, uniq_specs, reconstruct)
-            solve_ms = (time.perf_counter() - t0) * 1e3
-            _telemetry.add_phase("solve", solve_ms)
+            t1 = time.perf_counter()
+            solve_ms = (t1 - t0) * 1e3
+            _telemetry.add_phase("solve", t0, t1)
             # dedup fan-out (and the service answer cache) hand the SAME
             # arrays to multiple consumers — freeze them so a caller's
             # in-place edit raises instead of silently corrupting the
@@ -457,6 +451,7 @@ class DPEngine:
             cold = (warm_key not in self._warmed
                     or _backends.TRACE_COUNT != traces_before)
             _backends.lru_put(self._warmed, warm_key, True, _ROUTE_STATE_MAX)
+            drain_span.set_metadata(cold=int(cold))
             if drain_rep is not None:
                 drain_rep.cold = cold
                 drain_rep.explored = explored
@@ -467,15 +462,16 @@ class DPEngine:
                 answers = [None] * len(uniq_specs)
         self.last_drain = drain_rep
         responses = []
-        for i, r in enumerate(batch):
-            j = lane_of[r.digest]
-            responses.append(
-                DPResponse(rid=r.rid, problem=r.problem,
-                           answer=prob.extract(tables[j], r.spec),
-                           backend=chosen.name, batch_size=len(batch),
-                           solution=answers[j],
-                           deduped=uniq_idx[r.digest] != i,
-                           table=tables[j] if r.keep_table else None))
+        with _telemetry.trace_span("dp.extract"):
+            for i, r in enumerate(batch):
+                j = lane_of[r.digest]
+                responses.append(
+                    DPResponse(rid=r.rid, problem=r.problem,
+                               answer=prob.extract(tables[j], r.spec),
+                               backend=chosen.name, batch_size=len(batch),
+                               solution=answers[j],
+                               deduped=uniq_idx[r.digest] != i,
+                               table=tables[j] if r.keep_table else None))
 
         if rest:
             self._buckets[key] = rest

@@ -153,7 +153,7 @@ register_knob(Knob(
 
 register_knob(Knob(
     name="REPRO_TELEMETRY", kind="choice", what="telemetry mode",
-    choices=("off", "basic", "spans", "profile"), default="off",
+    choices=("off", "basic", "spans"), default="off",
     probe="basic",
     description="telemetry level; observability only — must never change "
                 "routing or results (DESIGN.md §8)"))

@@ -126,30 +126,34 @@ def reconstruct_batch(prob: DPProblem, specs: Sequence[Spec],
     traceback program; host-sourced args fall back to host walks; fused
     routes pass the in-launch-walked ``paths`` in and skip the traceback
     dispatch entirely (the phase still reports, at ~zero ms). The walk
-    and the decode loop each report their duration as a telemetry phase
-    (``traceback`` / ``decode``) — onto the engine's active drain report
-    when one is open, always into the registry histograms (no-op when
-    telemetry is off)."""
+    and the decode loop each report their start and end as a telemetry
+    phase (``traceback`` / ``decode``) — onto the engine's active drain
+    report when one is open, always into the registry histograms (no-op
+    when telemetry is off) — and run inside the profiler spans
+    ``dp.traceback`` / ``dp.decode``."""
     import time
 
     from repro.dp import telemetry as _telemetry
 
     spec0 = specs[0]
     t0 = time.perf_counter()
-    if paths is not None:
-        paths = list(paths)
-    elif source == "device":
-        starts = None
-        if spec0.uses_start:
-            starts = [start_cell(prob, t, s) for t, s in zip(tables, specs)]
-        paths = traceback_batch(argss, spec0, starts)
-    else:
-        paths = [traceback_host(a, s,
-                                start_cell(prob, t, s) if s.uses_start else -1)
-                 for a, s, t in zip(argss, specs, tables)]
+    with _telemetry.trace_span("dp.traceback"):
+        if paths is not None:
+            paths = list(paths)
+        elif source == "device":
+            starts = None
+            if spec0.uses_start:
+                starts = [start_cell(prob, t, s)
+                          for t, s in zip(tables, specs)]
+            paths = traceback_batch(argss, spec0, starts)
+        else:
+            paths = [traceback_host(a, s, start_cell(prob, t, s)
+                                    if s.uses_start else -1)
+                     for a, s, t in zip(argss, specs, tables)]
     t1 = time.perf_counter()
-    _telemetry.add_phase("traceback", (t1 - t0) * 1e3)
-    answers = [reconstruct_one(prob, s, t, a, source, path=p)
-               for s, t, a, p in zip(specs, tables, argss, paths)]
-    _telemetry.add_phase("decode", (time.perf_counter() - t1) * 1e3)
+    _telemetry.add_phase("traceback", t0, t1)
+    with _telemetry.trace_span("dp.decode"):
+        answers = [reconstruct_one(prob, s, t, a, source, path=p)
+                   for s, t, a, p in zip(specs, tables, argss, paths)]
+    _telemetry.add_phase("decode", t1, time.perf_counter())
     return answers

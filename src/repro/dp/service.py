@@ -259,9 +259,14 @@ class DPService:
         ``deadline_ms`` is relative to now and bounds *start* time — a
         ticket still in the backlog past it resolves to
         ``status="expired"``."""
-        prob = _registry.get(problem)
-        spec = prob.encode(**payload)
-        return self._submit(prob, spec, priority, deadline_ms, reconstruct)
+        with _telemetry.trace_span("dp.submit") as span:
+            prob = _registry.get(problem)
+            with _telemetry.trace_span("dp.encode"):
+                spec = prob.encode(**payload)
+            tid = self._submit(prob, spec, priority, deadline_ms,
+                               reconstruct)
+            span.set_metadata(tid=tid)
+        return tid
 
     def _submit(self, prob, spec: Spec, priority: int,
                 deadline_ms: Optional[float], reconstruct: bool,
@@ -280,7 +285,11 @@ class DPService:
         # length, which commits to the seed (non-step parameters) plus
         # every step payload — the same content commitment spec_digest
         # makes, minus an O(n) hash pass over the instance.
-        digest = chain_full if chain_full is not None else spec_digest(spec)
+        if chain_full is not None:
+            digest = chain_full
+        else:
+            with _telemetry.trace_span("dp.digest"):
+                digest = spec_digest(spec)
         now = time.monotonic()
         hit = strip_solution = None
         if serve is None:
@@ -604,11 +613,26 @@ class DPService:
         """One service step: expire stale tickets, refill the engine, drain
         one bucket. Returns the tids resolved this step (drained + newly
         expired)."""
-        resolved = self._expire()
-        self._sweep_sessions()
-        self._admit()
-        responses = self.engine.step(backend=backend,
-                                     bucket=self._drain_target())
+        with _telemetry.trace_span("dp.step") as span:
+            resolved = self._expire()
+            self._sweep_sessions()
+            with _telemetry.trace_span("dp.admit") as admit_span:
+                admit_span.set_metadata(tickets=self._admit())
+            responses = self.engine.step(backend=backend,
+                                         bucket=self._drain_target())
+            with _telemetry.trace_span("dp.resolve"):
+                self._resolve(responses, resolved)
+            self.stats["service_steps"] += 1
+            _telemetry.set_gauge("dp_service_backlog", self.backlog())
+            _telemetry.set_gauge("dp_service_inflight", len(self._inflight))
+            _telemetry.set_gauge("dp_service_cache_size", len(self._cache))
+            span.set_metadata(answered=len(resolved))
+        return resolved
+
+    def _resolve(self, responses: list, resolved: list) -> None:
+        """File a drain's responses: results, sessions, the answer cache and
+        per-request attribution. Appends each resolved tid to
+        ``resolved``."""
         drain = self.engine.last_drain if _telemetry.enabled() else None
         t_done = _telemetry.clock() if _telemetry.enabled() else 0.0
         for resp in responses:
@@ -651,19 +675,14 @@ class DPService:
                                           solution=resp.solution,
                                           backend=resp.backend),
                               self.cache_size)
-        self.stats["service_steps"] += 1
-        _telemetry.set_gauge("dp_service_backlog", self.backlog())
-        _telemetry.set_gauge("dp_service_inflight", len(self._inflight))
-        _telemetry.set_gauge("dp_service_cache_size", len(self._cache))
-        return resolved
 
     def _observe_phases(self, t: Ticket, resp, drain, span, t_done: float):
         """Per-request latency attribution from the drain report: feed the
         queue/dispatch/solve/traceback/decode histograms, and (``spans``
-        mode) replay the drain's timeline into the request's span. Solve/
-        traceback/decode are drain-level durations — each request in the
-        batch waited for the whole batched call, so the drain's duration IS
-        its latency contribution."""
+        mode) put the drain's phases into the request's span at the times
+        they ended. Solve/traceback/decode are drain-level durations — each
+        request in the batch waited for the whole batched call, so the
+        drain's duration IS its latency contribution."""
         phases = {
             "queue": (t.t_dispatched - t.t_enqueued) * 1e3,
             "dispatch": (drain.t_start - t.t_dispatched) * 1e3,
@@ -686,18 +705,13 @@ class DPService:
         span.add("batched", tt)
         if drain.cold:
             span.add("retraced", tt)
-        if resp.extended:
-            tt += drain.phases.get("extend", 0.0) / 1e3
-            span.add("extended", tt)
-        else:
-            tt += drain.phases.get("solve", 0.0) / 1e3
-            span.add("solved", tt)
-        if "traceback" in drain.phases:
-            tt += drain.phases["traceback"] / 1e3
-            span.add("traceback", tt)
-        if "decode" in drain.phases:
-            tt += drain.phases["decode"] / 1e3
-            span.add("decoded", tt)
+        for phase, event in (("extend" if resp.extended else "solve",
+                              "extended" if resp.extended else "solved"),
+                             ("traceback", "traceback"),
+                             ("decode", "decoded")):
+            if phase in drain.bounds:
+                tt = drain.bounds[phase][1]
+                span.add(event, tt)
         if resp.deduped:
             span.add("dedup_fanout", tt)
         _telemetry.finish_span(span.add("resolved", t_done))

@@ -1,5 +1,5 @@
-"""Unified telemetry: request spans, metrics registry, routing audit,
-latency attribution, and exporters (DESIGN.md §8).
+"""Unified telemetry: request spans, metrics registry, latency
+attribution, profiler spans, and exporters (DESIGN.md §8).
 
 The serving stack can batch, dedup, shard, and re-route — but until now it
 could only *observe* end-to-end wall time. This module is the process-wide
@@ -19,27 +19,27 @@ observability subsystem every layer reports into:
     weak-referenced *stat sources* so the engine/service compatibility
     ``stats`` dicts are absorbed into one snapshot instead of being
     scraped ad hoc.
-  * **Routing audit.** Each ``autotune.rank``/``rank_batch`` decision (and
-    each engine drain-route resolution) records its candidates with
-    measured-vs-analytical scores, the measurement regime, and the chosen
-    backend into a bounded ring surfaced through
-    ``dp.routing_report()["decisions"]`` — the attribution data the
-    ROADMAP's learned-cost-model item trains on.
+  * **Profiler spans.** :func:`trace_span` names the host work of each
+    layer (``dp.submit``, ``dp.encode``, ``dp.digest``, ``dp.step``,
+    ``dp.drain``, ``dp.stack``, ``dp.launch``, ``dp.fetch``, …) as a
+    ``jax.profiler.TraceAnnotation`` while the profiler captures, so the
+    host path lands on the device trace's clock; counts such as the
+    host→device bytes of a drain ride on the span as its args. Outside a
+    capture it costs one ``is_enabled()`` check.
   * **Exporters.** :func:`snapshot` (JSON-able dict), :func:`save_snapshot`,
-    :func:`to_prometheus` (text exposition format), and — in ``profile``
-    mode — a ``jax.profiler`` trace annotation around every engine drain so
-    drains show up as named ranges in TensorBoard profiles.
+    :func:`to_prometheus` (text exposition format).
 
 Overhead policy (the §8 contract): telemetry is **off by default and
 off-is-free** — every helper is a guarded no-op below its level, all
 timestamps come from the monotonic :func:`clock` (``time.perf_counter``),
 buffers are bounded rings, and nothing on the solve path adds a host sync
 (phase timings bracket the numpy conversions the engine already blocks
-on). ``REPRO_TELEMETRY={off,basic,spans,profile}`` selects the level,
+on). ``REPRO_TELEMETRY={off,basic,spans}`` selects the level,
 validated exactly like ``REPRO_KERNELS`` (a typo raises, it never silently
 disables observability); ``configure()`` overrides it in-process. CI gates
 ``spans``-mode overhead at ≤5 % wall time on the service bench with
-bit-identical routing and results vs ``off``.
+bit-identical routing and results vs ``off``. The profiler spans need no
+level: capturing a profile turns them on.
 
 The ``repro.dp`` ``logging`` hierarchy also lives here:
 :func:`get_logger` hands out ``logging.getLogger("repro.dp.<mod>")``
@@ -57,7 +57,9 @@ import time
 import weakref
 from collections import OrderedDict, deque
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Dict, List, Optional, Tuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.dp import envknobs
 
@@ -65,9 +67,8 @@ __all__ = [
     "REGISTRY", "Counter", "DrainReport", "Gauge", "Histogram",
     "MetricsRegistry", "Span", "add_phase", "clock", "configure", "count",
     "drain_scope", "enabled", "get_logger", "log_level", "mode",
-    "new_span", "observe_ms", "record_route_decision", "reset",
-    "routing_audit", "save_snapshot", "set_gauge", "snapshot",
-    "spans_snapshot", "to_prometheus",
+    "new_span", "observe_ms", "reset", "save_snapshot", "set_gauge",
+    "snapshot", "spans_snapshot", "to_prometheus", "trace_span", "tracing",
 ]
 
 #: the one clock every span event and phase timing uses — monotonic,
@@ -76,13 +77,13 @@ __all__ = [
 clock = time.perf_counter
 
 # ---------------------------------------------------------------------------
-# Mode knob: REPRO_TELEMETRY={off,basic,spans,profile}
+# Mode knob: REPRO_TELEMETRY={off,basic,spans}
 # ---------------------------------------------------------------------------
 ENV_MODE = "REPRO_TELEMETRY"
 #: aliased from the central knob catalog (dp/envknobs.py)
 _MODES = envknobs.knob(ENV_MODE).choices
 _LEVEL_OF = {m: i for i, m in enumerate(_MODES)}
-LEVEL_OFF, LEVEL_BASIC, LEVEL_SPANS, LEVEL_PROFILE = 0, 1, 2, 3
+LEVEL_OFF, LEVEL_BASIC, LEVEL_SPANS = 0, 1, 2
 
 _mode_lock = threading.Lock()
 _mode: Optional[str] = None          # resolved mode (env or configure())
@@ -530,6 +531,47 @@ def clear_spans() -> None:
 
 
 # ---------------------------------------------------------------------------
+# Profiler spans: the host path on the device trace's clock
+# ---------------------------------------------------------------------------
+class _NoSpan:
+    """The span :func:`trace_span` hands out while the profiler is not
+    capturing: enters, exits and takes metadata, and records nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+
+
+def tracing() -> bool:
+    """Whether the profiler is capturing (``jax.profiler.start_trace``), so
+    :func:`trace_span` records. Callers compute costly span args only when
+    this holds."""
+    return TraceAnnotation.is_enabled()
+
+
+def trace_span(name: str, **args):
+    """A named host span for the profiler: a ``jax.profiler.
+    TraceAnnotation`` carrying ``args`` while the profiler captures, one
+    shared no-op otherwise. Counts known only at the end go on through the
+    span's ``set_metadata(**args)``. Every name starts with ``dp.``; the
+    benchmark's trace reduction charges device idle time to the innermost
+    one open."""
+    if not TraceAnnotation.is_enabled():
+        return _NO_SPAN
+    return TraceAnnotation(name, **args)
+
+
+# ---------------------------------------------------------------------------
 # Drain scope: per-drain phase attribution shared engine ↔ reconstruct
 # ---------------------------------------------------------------------------
 @dataclasses.dataclass
@@ -537,7 +579,8 @@ class DrainReport:
     """Phase timings and decision facts of ONE engine bucket drain. The
     engine publishes the last one (``engine.last_drain``); the service
     reads it to attribute span events and per-phase histograms to every
-    request the drain resolved."""
+    request the drain resolved. ``phases`` holds each phase's duration in
+    ms, ``bounds`` its start and end on the :func:`clock` timebase."""
 
     bucket: tuple
     backend: str
@@ -545,6 +588,8 @@ class DrainReport:
     unique: int
     t_start: float
     phases: Dict[str, float] = dataclasses.field(default_factory=dict)
+    bounds: Dict[str, Tuple[float, float]] = dataclasses.field(
+        default_factory=dict)
     cold: bool = False
     explored: bool = False
     sharded: bool = False
@@ -567,9 +612,7 @@ def drain_scope(bucket: tuple, backend: str, batch_size: int, unique: int):
     """Open the per-drain attribution context (``None`` in ``off`` mode).
     While active, :func:`add_phase` calls — including from
     ``reconstruct.reconstruct_batch`` deep below the engine — land on this
-    drain's report. In ``profile`` mode the body also runs inside a
-    ``jax.profiler.TraceAnnotation`` named range so drains are visible in
-    TensorBoard traces."""
+    drain's report."""
     if not enabled("basic"):
         yield None
         return
@@ -578,23 +621,9 @@ def drain_scope(bucket: tuple, backend: str, batch_size: int, unique: int):
                          t_start=clock())
     prev = getattr(_TLS, "drain", None)
     _TLS.drain = report
-    annotation = None
-    if enabled("profile"):
-        try:
-            import jax
-            annotation = jax.profiler.TraceAnnotation(
-                f"dp_drain:{bucket[0]}:{backend}:b{batch_size}")
-            annotation.__enter__()
-        except Exception:           # profiling must never break a drain
-            annotation = None
     try:
         yield report
     finally:
-        if annotation is not None:
-            try:
-                annotation.__exit__(None, None, None)
-            except Exception:
-                pass
         _TLS.drain = prev
 
 
@@ -602,72 +631,29 @@ def current_drain() -> Optional[DrainReport]:
     return getattr(_TLS, "drain", None)
 
 
-def add_phase(phase: str, ms: float) -> None:
-    """Record one phase duration: onto the active drain report (if any)
-    and into the ``dp_engine_<phase>_ms`` histogram. No-op below
-    ``basic``."""
+def add_phase(phase: str, t0: float, t1: float) -> None:
+    """Record one phase that ran from ``t0`` to ``t1`` (:func:`clock`
+    times): onto the active drain report (if any) and into the
+    ``dp_engine_<phase>_ms`` histogram. A phase recorded in pieces (an
+    extend drain's lanes) sums their durations and spans their bounds.
+    No-op below ``basic``."""
     if not enabled("basic"):
         return
+    ms = (t1 - t0) * 1e3
     report = current_drain()
     if report is not None:
         report.phases[phase] = report.phases.get(phase, 0.0) + ms
+        lo, hi = report.bounds.get(phase, (t0, t1))
+        report.bounds[phase] = (min(lo, t0), max(hi, t1))
     REGISTRY.histogram(f"dp_engine_{phase}_ms").observe(ms)
-
-
-# ---------------------------------------------------------------------------
-# Routing audit
-# ---------------------------------------------------------------------------
-AUDIT_RING_MAX = 2048
-_AUDIT: "deque" = deque(maxlen=AUDIT_RING_MAX)
-_audit_lock = threading.Lock()
-
-
-def audit_enabled() -> bool:
-    return enabled("spans")
-
-
-def record_route_decision(kind: str, shape_key: tuple, regime,
-                          candidates: List[dict], chosen: str,
-                          **extra) -> None:
-    """File one routing decision. ``candidates`` rows carry per-backend
-    ``measured_ms`` (None = unmeasured in this regime) and
-    ``analytical_cost`` — the measured-vs-analytical evidence the decision
-    was made on. Bounded ring; no-op unless ``spans`` mode."""
-    if not audit_enabled():
-        return
-    entry = {
-        "t": clock(),
-        "kind": kind,
-        "shape_key": repr(tuple(shape_key)),
-        "regime": repr(regime) if regime else "single",
-        "candidates": candidates,
-        "chosen": chosen,
-    }
-    entry.update(extra)
-    with _audit_lock:
-        _AUDIT.append(entry)
-    count("dp_routing_decisions_total")
-
-
-def routing_audit(limit: Optional[int] = None) -> List[dict]:
-    """Most recent routing decisions (oldest first)."""
-    with _audit_lock:
-        items = list(_AUDIT)
-    return items[-limit:] if limit is not None else items
-
-
-def clear_audit() -> None:
-    with _audit_lock:
-        _AUDIT.clear()
 
 
 # ---------------------------------------------------------------------------
 # Exporters
 # ---------------------------------------------------------------------------
-def snapshot(spans_limit: int = 256, audit_limit: int = 256) -> dict:
+def snapshot(spans_limit: int = 256) -> dict:
     """One JSON-able dict of everything: mode, metrics, absorbed stat
-    sources, recent spans, recent routing decisions, and the trace-log
-    compatibility counters."""
+    sources, recent spans, and the trace-log compatibility counters."""
     from repro.dp import backends as _backends
 
     return {
@@ -681,7 +667,6 @@ def snapshot(spans_limit: int = 256, audit_limit: int = 256) -> dict:
             for name, h in sorted(REGISTRY.histograms().items())},
         "sources": REGISTRY.sources(),
         "spans": spans_snapshot(limit=spans_limit),
-        "routing_audit": routing_audit(limit=audit_limit),
         "trace_count": _backends.TRACE_COUNT,
         "trace_log_len": len(_backends.TRACE_LOG),
     }

@@ -60,6 +60,7 @@ def chunked_scan_pallas(x, decay, h0, *, chunk: int = DEFAULT_CHUNK,
     grid = (d // bd, t // chunk)
     h_all, h_last = pl.pallas_call(
         _kernel,
+        name="chunked_scan_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((chunk, bd), lambda j, c: (c, j)),

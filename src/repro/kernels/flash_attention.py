@@ -80,6 +80,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
     kernel = functools.partial(_kernel, scale=scale, causal=causal, bq=bq, bk=bk)
     return pl.pallas_call(
         kernel,
+        name="flash_attention_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),

@@ -160,6 +160,7 @@ def _antidiag_call(arrs, meta, with_args, interpret):
         out_shape.append(jax.ShapeDtypeStruct((P, rows, LANES), jnp.int32))
     out = pl.pallas_call(
         _make_antidiag_kernel(meta, with_args),
+        name="grid_pipeline_pallas_antidiag" + ("_args" if with_args else ""),
         grid=(fronts,),
         in_specs=[pl.BlockSpec((None, Lm + 2 * P, nv, LANES),
                                lambda t: (t, 0, 0, 0))],
@@ -269,6 +270,7 @@ def _spandiag_call(arrs, meta, with_args, interpret):
         out_shape.append(jax.ShapeDtypeStruct((P, rows, LANES), jnp.int32))
     out = pl.pallas_call(
         _make_spandiag_kernel(meta, with_args),
+        name="grid_pipeline_pallas_spandiag" + ("_args" if with_args else ""),
         out_shape=out_shape,
         compiler_params=vmem_params(2 * 4 * len(out_shape) * P * rows * LANES),
         interpret=interpret,

@@ -126,6 +126,7 @@ def _call(wtab, n, with_args, interpret):
         out_shape.append(jax.ShapeDtypeStruct((rows, LANES), jnp.int32))
     out = pl.pallas_call(
         _make_kernel(n, with_args),
+        name="mcm_pipeline_pallas" + ("_args" if with_args else ""),
         out_shape=out_shape,
         compiler_params=vmem_params(2 * vmem_bytes(n)),
         interpret=interpret,

@@ -212,6 +212,7 @@ def _tiled_call(wtab, n, plan, with_args, fused, interpret):
               + (2 * 4 * 3 * nvec(L) * LANES if fused else 0))
     outs = pl.pallas_call(
         _make_tiled_kernel(n, plan, fused),
+        name="mcm_tiled_pallas" + ("_fused" if fused else ""),
         grid=(n - 1, J),
         in_specs=[pl.BlockSpec(
             (None, None, E, nv, LANES),

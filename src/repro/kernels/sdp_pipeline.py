@@ -173,6 +173,7 @@ def _call(init, offsets, op, n, geom, weights, with_args, interpret):
     out = pl.pallas_call(
         _make_kernel(offsets, op, B, A, C, weighted=weights is not None,
                      with_args=with_args),
+        name="sdp_pipeline_pallas" + ("_args" if with_args else ""),
         grid=(nc,),
         in_specs=in_specs,
         out_specs=out_specs,

@@ -74,6 +74,7 @@ def tropical_matmul_pallas(a, b, av=None, gv=None, bv=None, *,
     grid = (m // bm, n // bn, k // bk)
     return pl.pallas_call(
         _kernel,
+        name="tropical_matmul_pallas",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -1,9 +1,11 @@
 """Telemetry layer (DESIGN.md §8): mode knobs, metrics registry, request
-spans, routing audit, drain attribution, thread safety, exporters, and the
+spans, drain attribution, profiler spans, thread safety, exporters, and the
 service accounting invariant."""
+import glob
 import json
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -22,7 +24,6 @@ def _telemetry_isolated(monkeypatch):
         telemetry.reset()
         telemetry.REGISTRY.reset()
         telemetry.clear_spans()
-        telemetry.clear_audit()
 
     clean()
     yield
@@ -287,32 +288,8 @@ def test_submitted_balances_under_mixed_traffic():
 
 
 # ---------------------------------------------------------------------------
-# Routing audit
+# Routing is observability-independent
 # ---------------------------------------------------------------------------
-def test_routing_report_carries_audit_decisions():
-    telemetry.configure("spans")
-    eng = dp.DPEngine(max_batch=8)
-    for kw in _mcm_payloads(3):
-        eng.submit("mcm", **kw)
-    eng.run()
-    decisions = dp.routing_report()["decisions"]
-    assert decisions
-    kinds = {d["kind"] for d in decisions}
-    assert "drain" in kinds and ("rank" in kinds or "rank_batch" in kinds)
-    ranked = next(d for d in decisions if d["kind"].startswith("rank"))
-    assert ranked["chosen"]
-    assert all({"backend", "measured_ms", "analytical_cost"} <= set(c)
-               for c in ranked["candidates"])
-
-
-def test_audit_silent_below_spans():
-    telemetry.configure("basic")
-    eng = dp.DPEngine(max_batch=8)
-    eng.submit("mcm", dims=[4, 5, 6, 7])
-    eng.run()
-    assert telemetry.routing_audit() == []
-
-
 def test_off_mode_routing_bit_identical():
     """REPRO_TELEMETRY must be observability only: same traffic, same
     routes, same answers with it off and on."""
@@ -344,7 +321,6 @@ def test_snapshot_and_save(tmp_path):
     assert snap["counters"]["dp_service_completed_total"] == 1
     assert "dp_service_latency_ms" in snap["histograms"]
     assert any(s["tid"] == tid for s in snap["spans"])
-    assert snap["routing_audit"]
     path = telemetry.save_snapshot(str(tmp_path / "snap.json"))
     assert json.load(open(path))["mode"] == "spans"
 
@@ -370,3 +346,156 @@ def test_kernel_entry_counter():
     mode = ops.kernel_mode()
     assert telemetry.REGISTRY.counters()[
         f"dp_kernel_tropical_matmul_{mode}_total"] == 1
+
+
+# ---------------------------------------------------------------------------
+# Profiler spans (dp.*)
+# ---------------------------------------------------------------------------
+def test_trace_span_builds_nothing_while_not_capturing(monkeypatch):
+    """Outside a profiler capture every span is the one shared no-op: no
+    ``TraceAnnotation`` is built, on the span helper or on a whole
+    service round."""
+    built = []
+
+    class Counting(jax.profiler.TraceAnnotation):
+        def __init__(self, *a, **kw):
+            built.append(a)
+            super().__init__(*a, **kw)
+
+    monkeypatch.setattr(telemetry, "TraceAnnotation", Counting)
+    assert not telemetry.tracing()
+    span = telemetry.trace_span("dp.test", n=1)
+    assert span is telemetry.trace_span("dp.other")
+    with span as inner:
+        inner.set_metadata(bytes=3)
+    svc = dp.DPService(max_batch=4, mesh=None)
+    tids = [svc.submit("mcm", reconstruct=True, **kw)
+            for kw in _mcm_payloads(3)]
+    out = svc.run()
+    assert all(out[t].status == "done" for t in tids)
+    assert built == []
+
+
+#: a small gotoh batch: reads of 13 against windows of 19 (a shape no other
+#: test drains, so its first drain here traces its programs)
+_SCORING = {"match": 1.0, "mismatch": -4.0, "gap_open": -7.0,
+            "gap_extend": -1.0}
+_ROUNDS, _LANES = 2, 4
+_SPAN_NAMES = ("dp.submit", "dp.encode", "dp.digest", "dp.step", "dp.admit",
+               "dp.drain", "dp.stack", "dp.launch", "dp.fetch",
+               "dp.traceback", "dp.decode", "dp.extract", "dp.resolve")
+#: span -> the span it runs inside
+_PARENT = {"dp.encode": "dp.submit", "dp.digest": "dp.submit",
+           "dp.admit": "dp.step", "dp.drain": "dp.step",
+           "dp.stack": "dp.drain", "dp.launch": "dp.drain",
+           "dp.fetch": "dp.drain", "dp.traceback": "dp.drain",
+           "dp.decode": "dp.drain", "dp.extract": "dp.step",
+           "dp.resolve": "dp.step"}
+
+
+@pytest.fixture(scope="module")
+def gotoh_capture(tmp_path_factory):
+    """Two rounds of four distinct 13x19 gotoh pairs with
+    ``reconstruct=True`` through ``DPService`` under a CPU profiler
+    capture. Returns ``(events, payloads)``: every ``dp.*`` host event as
+    ``(start, end, name, args)`` in time order, and each round's
+    payloads."""
+    log_dir = str(tmp_path_factory.mktemp("profile"))
+    rng = np.random.default_rng(7)
+    rounds = [[{"x": rng.integers(0, 4, 13), "y": rng.integers(0, 4, 19),
+                **_SCORING} for _ in range(_LANES)] for _ in range(_ROUNDS)]
+    svc = dp.DPService(max_batch=_LANES, mesh=None, feedback=False)
+    jax.profiler.start_trace(log_dir)
+    try:
+        for payloads in rounds:
+            tids = [svc.submit("gotoh", reconstruct=True, **kw)
+                    for kw in payloads]
+            out = svc.run()
+            assert all(out[t].solution is not None for t in tids)
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(f"{log_dir}/plugins/profile/*/*.xplane.pb")
+    events = []
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("dp."):
+                    events.append((e.start_ns, e.start_ns + e.duration_ns,
+                                   e.name, dict(e.stats)))
+    return sorted(events), rounds
+
+
+def _named(events, name):
+    return [ev for ev in events if ev[2] == name]
+
+
+def test_capture_records_every_span(gotoh_capture):
+    events, _ = gotoh_capture
+    names = {name for _, _, name, _ in events}
+    assert set(_SPAN_NAMES) <= names
+    assert len(_named(events, "dp.submit")) == _ROUNDS * _LANES
+    assert len(_named(events, "dp.drain")) == _ROUNDS
+    assert [args["tid"] for *_, args in _named(events, "dp.submit")] == \
+        list(range(_ROUNDS * _LANES))
+    assert all(args == {"lanes": _LANES, "unique": _LANES,
+                        "cold": int(i == 0)}
+               for i, (*_, args) in enumerate(_named(events, "dp.drain")))
+
+
+def test_capture_spans_nest(gotoh_capture):
+    events, _ = gotoh_capture
+    for name, parent in _PARENT.items():
+        outer = _named(events, parent)
+        for a, b, _, _ in _named(events, name):
+            assert any(pa <= a and b <= pb for pa, pb, _, _ in outer), name
+    # the service's step answers each round whole
+    assert [args["answered"] for *_, args in _named(events, "dp.step")
+            if args["answered"]] == [_LANES] * _ROUNDS
+
+
+def test_capture_counts_host_to_device_bytes(gotoh_capture):
+    """``h2d_bytes`` of each drain's ``dp.stack`` is the bytes of the
+    lanes' ``device_arrays()``, counted here from the payloads."""
+    events, rounds = gotoh_capture
+    gotoh = dp.get_problem("gotoh")
+    stacks = _named(events, "dp.stack")
+    assert len(stacks) == _ROUNDS
+    for (*_, args), payloads in zip(stacks, rounds):
+        arrays = [a for kw in payloads
+                  for a in gotoh.encode(**kw).device_arrays()]
+        assert args["h2d_bytes"] == sum(a.nbytes for a in arrays)
+        assert args["arrays"] == len(arrays)
+    fetches = _named(events, "dp.fetch")
+    assert len(fetches) == _ROUNDS
+    assert all(args["d2h_bytes"] > 0 for *_, args in fetches)
+
+
+def test_capture_counts_compiles_on_first_drain_only(gotoh_capture):
+    events, _ = gotoh_capture
+    compiles = [args["compiles"] for *_, args in _named(events, "dp.launch")]
+    assert len(compiles) == _ROUNDS
+    assert compiles[0] >= 1
+    assert compiles[1:] == [0] * (_ROUNDS - 1)
+
+
+def test_drain_report_phase_bounds_are_real_times():
+    """A drain report keeps each phase's start and end; the request's span
+    places ``solved``/``traceback``/``decoded`` at those ends."""
+    telemetry.configure("spans")
+    svc = dp.DPService(max_batch=8, mesh=None)
+    tid = svc.submit("mcm", reconstruct=True, dims=[4, 5, 6, 7, 8])
+    res = svc.run()[tid]
+    rep = svc.engine.last_drain
+    assert set(rep.bounds) == {"solve", "traceback", "decode"}
+    solve, tb, dec = (rep.bounds[p] for p in ("solve", "traceback",
+                                               "decode"))
+    assert rep.t_start <= solve[0] <= solve[1] <= tb[0] <= tb[1] \
+        <= dec[0] <= dec[1]
+    for phase, (t0, t1) in rep.bounds.items():
+        assert rep.phases[phase] == pytest.approx((t1 - t0) * 1e3)
+    events = dict(res.span.events)
+    assert events["solved"] == solve[1]
+    assert events["traceback"] == tb[1]
+    assert events["decoded"] == dec[1]
