@@ -25,7 +25,7 @@ import numpy as np
 
 from repro.dp import telemetry as _telemetry
 from repro.dp.problem import (LinearSpec, Spec, TriangularSpec,
-                              family_class)
+                              family_class, plane_builder)
 
 #: (backend_name, shape_key) appended every time a batched callable is traced.
 #: Bounded at :data:`TRACE_LOG_MAX` (oldest entries dropped) so a long-running
@@ -95,6 +95,32 @@ def stack_slots(specs, lane_arrays: Callable, sharding=None) -> tuple:
                 h2d_bytes=sum(a.nbytes for arrs in lanes for a in arrs),
                 arrays=sum(len(arrs) for arrs in lanes))
         return tuple(stack_bucket(slot, sharding) for slot in zip(*lanes))
+
+
+def stack_sources(specs, sharding=None) -> tuple:
+    """The drain's host→device copy when the program builds the lanes'
+    planes itself (:func:`common_source`): each source slot's lanes are
+    stacked on the host and sent as one array, or placed over the mesh.
+    Runs under ``dp.stack`` like :func:`stack_slots`, which also counts
+    the lanes whose planes the program builds (``source_lanes``)."""
+    import jax.numpy as jnp
+
+    with _telemetry.trace_span("dp.stack") as span:
+        slots = [np.stack(slot)
+                 for slot in zip(*(s.source.arrays for s in specs))]
+        if _telemetry.tracing():
+            span.set_metadata(h2d_bytes=sum(a.nbytes for a in slots),
+                              arrays=len(slots), source_lanes=len(specs))
+        if sharding is None:
+            return tuple(jnp.asarray(a) for a in slots)
+        return tuple(sharding.place(a) for a in slots)
+
+
+def common_source(specs) -> Optional[str]:
+    """The plane builder every spec of a bucket names in its ``source``
+    (``GridSpec.source``), or ``None`` when any lane has none or another."""
+    names = {None if s.source is None else s.source.builder for s in specs}
+    return names.pop() if len(names) == 1 else None
 
 
 def launch(program: Callable, *stacked):
@@ -463,23 +489,33 @@ def grid_backend(name: str, jax_fn: Callable, cost: Callable,
     def _batch(fn, specs, key, sharding=None):
         spec0 = specs[0]
         meta = spec0.static_meta()
+        source = common_source(specs)
+        planes = (plane_builder(source) if source is not None
+                  else lambda a, meta: a)
 
         def build():
             def call(*stacked):
                 log_trace(key)
-                return jax.vmap(lambda *a: fn(a, meta))(*stacked)
+                return jax.vmap(lambda *a: fn(planes(a, meta), meta))(
+                    *stacked)
 
             if sharding is None:
                 return jax.jit(call)
             return sharding.wrap(call)
 
         cached = lru_cached(_BATCH_CACHE, key, build, _BATCH_CACHE_MAX)
+        if source is not None:
+            return launch(cached, *stack_sources(specs, sharding))
         return launch(cached, *stack_slots(specs, lambda s: s.device_arrays(),
                                            sharding))
 
     def _batch_key(specs, sharding) -> tuple:
+        """The program's cache key; a bucket whose planes the program
+        builds (:func:`common_source`) names the builder in it."""
         shard_tag = sharding.cache_suffix() if sharding is not None else ()
-        return (name, specs[0].shape_key()) + tag() + shard_tag
+        source = common_source(specs)
+        source_tag = () if source is None else (("source", source),)
+        return (name, specs[0].shape_key()) + tag() + shard_tag + source_tag
 
     def batch_run(specs, sharding=None) -> list:
         table, = fetch(_batch(jax_fn, specs, _batch_key(specs, sharding),

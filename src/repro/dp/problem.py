@@ -722,6 +722,34 @@ class TriangularSpec:
                              self.min_prefix_len())[0]
 
 
+#: plane builders by name: ``build(arrays, meta) -> device_arrays()``
+_PLANE_BUILDERS: dict = {}
+
+
+def register_plane_builder(name: str, build: Callable) -> Callable:
+    """Register the pure-jnp builder that a :class:`PlaneSource` named
+    ``name`` is expanded with inside a batch program."""
+    if name in _PLANE_BUILDERS:
+        raise ValueError(f"duplicate plane builder {name!r}")
+    _PLANE_BUILDERS[name] = build
+    return build
+
+
+def plane_builder(name: str) -> Callable:
+    return _PLANE_BUILDERS[name]
+
+
+@dataclasses.dataclass(frozen=True)
+class PlaneSource:
+    """Compact form of an antidiag grid's planes (DESIGN.md §9): the
+    registered builder ``builder`` maps ``arrays`` (small per-instance host
+    arrays whose shapes follow from the spec's ``static_meta()``) and the
+    meta to the spec's ``device_arrays()``, bit for bit."""
+
+    builder: str
+    arrays: tuple
+
+
 @dataclasses.dataclass(frozen=True)
 class GridSpec:
     """Multi-plane 2-D wavefront instance (DESIGN.md §9).
@@ -750,6 +778,12 @@ class GridSpec:
     per-plane leaf scores). Layout: ``(planes·num_cells(n),)`` flat,
     diagonal-major per plane. The packed arg of a cell is
     ``e·len(rules) + r``.
+
+    ``source`` (optional, antidiag): a :class:`PlaneSource` from which a
+    batch program builds ``device_arrays()`` on the device instead of
+    receiving them. The planes stay the spec's content — digests, host
+    routes and streaming read them — and any spec derived from other
+    planes drops the source.
     """
 
     rows: int
@@ -763,6 +797,8 @@ class GridSpec:
     rule_weights: Optional[np.ndarray] = None
     init: Optional[np.ndarray] = None
     init_mask: Optional[np.ndarray] = None
+    source: Optional[PlaneSource] = dataclasses.field(default=None,
+                                                      compare=False)
 
     family: ClassVar[str] = "grid"
     uses_start: ClassVar[bool] = True
@@ -1065,10 +1101,11 @@ class GridSpec:
                 self, cols=L,
                 weights=np.ascontiguousarray(self.weights[:, :, :L]),
                 init=np.ascontiguousarray(self.init[:, :, :L]),
-                init_mask=np.ascontiguousarray(self.init_mask[:, :, :L]))
+                init_mask=np.ascontiguousarray(self.init_mask[:, :, :L]),
+                source=None)
         return dataclasses.replace(
             self, rows=L, cols=L,
-            init=np.ascontiguousarray(self.init[:, :L]))
+            init=np.ascontiguousarray(self.init[:, :L]), source=None)
 
     def extension_delta(self, prefix: "GridSpec") -> dict:
         same = (isinstance(prefix, GridSpec)
@@ -1114,7 +1151,8 @@ class GridSpec:
                 self, cols=self.cols + k,
                 weights=np.concatenate([self.weights, w], axis=2),
                 init=np.concatenate([self.init, ini], axis=2),
-                init_mask=np.concatenate([self.init_mask, mask], axis=2))
+                init_mask=np.concatenate([self.init_mask, mask], axis=2),
+                source=None)
         else:
             k = int(delta["steps"])
             if k < 1:
@@ -1125,7 +1163,7 @@ class GridSpec:
                                  f"({self.planes}, {k}), got {ini.shape}")
             ext = dataclasses.replace(
                 self, rows=self.rows + k, cols=self.cols + k,
-                init=np.concatenate([self.init, ini], axis=1))
+                init=np.concatenate([self.init, ini], axis=1), source=None)
         ext.validate()
         return ext
 

@@ -36,12 +36,14 @@ import numpy as np
 
 from repro.core import mcm as _mcm
 from repro.core import sdp as _sdp
-from repro.dp.problem import (DPProblem, GridSpec, LinearSpec, TriangularSpec,
-                              lin_index)
+from repro.dp.problem import (DPProblem, GridSpec, LinearSpec, PlaneSource,
+                              TriangularSpec, lin_index,
+                              register_plane_builder)
 from repro.dp.registry import register
 
 _NEG = -np.inf
 _POS = np.inf
+_I32 = np.iinfo(np.int32)
 
 
 # ===========================================================================
@@ -715,9 +717,64 @@ def _gotoh_encode(x, y, match=2.0, mismatch=-1.0, gap_open=-3.0,
     init[1, 1:, 0] = gap_open + gap_extend * np.arange(m)
     init[2, 0, 1:] = gap_open + gap_extend * np.arange(c)
     spec = GridSpec(rows=R, cols=C, op="max", schedule="antidiag", planes=3,
-                    moves=_GOTOH_MOVES, weights=w, init=init, init_mask=mask)
+                    moves=_GOTOH_MOVES, weights=w, init=init, init_mask=mask,
+                    source=_gotoh_source(
+                        x, y, (match, mismatch, gap_open, gap_extend), init))
     spec.validate()
     return spec
+
+
+def _gotoh_source(x, y, scores, init):
+    """The planes' compact form, one int32 vector: the symbols, then the
+    bits of the float32 scores (match, mismatch, open, extend) and of the
+    two gap-ramp edges as the host computed them. ``None`` for symbols the
+    int32 cast would not keep distinct."""
+    if not all(a.dtype.kind in "biu" and a.min() >= _I32.min
+               and a.max() <= _I32.max for a in (x, y)):
+        return None
+    bits = np.concatenate([np.asarray(scores, np.float64).astype(np.float32),
+                           init[1, 1:, 0], init[2, 0, 1:]]).view(np.int32)
+    return PlaneSource("gotoh", (np.concatenate(
+        [x.astype(np.int32), y.astype(np.int32), bits]),))
+
+
+def _gotoh_planes(arrays, meta):
+    """``_gotoh_encode``'s weights, init and float mask from its source,
+    in jnp: selects only, so every entry is the host's bit for bit."""
+    import jax
+    import jax.numpy as jnp
+
+    packed, = arrays
+    R, C = meta[3], meta[4]
+    m, c = R - 1, C - 1
+    x, y = packed[:m], packed[m:m + c]
+    f = jax.lax.bitcast_convert_type(packed[m + c:], jnp.float32)
+    match, mismatch, gap_open, gap_extend = f[0], f[1], f[2], f[3]
+    neg = jnp.float32(_NEG)
+    i = jnp.arange(R)[:, None]
+    j = jnp.arange(C)[None, :]
+    row, col = i >= 1, j >= 1
+    # index 0 stands in for the boundary row / column, masked below
+    xs = x[jnp.maximum(i - 1, 0)]
+    ys = y[jnp.maximum(j - 1, 0)]
+    s = jnp.where(row & col, jnp.where(xs == ys, match, mismatch), neg)
+    full = (R, C)
+    w = jnp.stack([s, s, s,
+                   jnp.broadcast_to(jnp.where(row, gap_open, neg), full),
+                   jnp.broadcast_to(jnp.where(row, gap_extend, neg), full),
+                   jnp.broadcast_to(jnp.where(col, gap_open, neg), full),
+                   jnp.broadcast_to(jnp.where(col, gap_extend, neg), full)])
+    up = f[4 + jnp.maximum(i - 1, 0)]                 # init[1, 1:, 0]
+    left = f[4 + m + jnp.maximum(j - 1, 0)]           # init[2, 0, 1:]
+    init = jnp.stack([jnp.where((i == 0) & (j == 0), jnp.float32(0), neg),
+                      jnp.where(row & (j == 0), up, neg),
+                      jnp.where((i == 0) & col, left, neg)])
+    mask = jnp.broadcast_to(((i == 0) | (j == 0)).astype(jnp.float32),
+                            (3, R, C))
+    return w, init, mask
+
+
+register_plane_builder("gotoh", _gotoh_planes)
 
 
 def _gotoh_oracle(x, y, match=2.0, mismatch=-1.0, gap_open=-3.0,

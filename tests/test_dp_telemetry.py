@@ -457,16 +457,20 @@ def test_capture_spans_nest(gotoh_capture):
 
 def test_capture_counts_host_to_device_bytes(gotoh_capture):
     """``h2d_bytes`` of each drain's ``dp.stack`` is the bytes of the
-    lanes' ``device_arrays()``, counted here from the payloads."""
+    arrays it sends: gotoh lanes carry a plane source, so each source slot
+    goes as one array of every lane's slot, counted here from the
+    payloads; ``source_lanes`` counts the lanes whose planes the program
+    builds."""
     events, rounds = gotoh_capture
     gotoh = dp.get_problem("gotoh")
     stacks = _named(events, "dp.stack")
     assert len(stacks) == _ROUNDS
     for (*_, args), payloads in zip(stacks, rounds):
-        arrays = [a for kw in payloads
-                  for a in gotoh.encode(**kw).device_arrays()]
-        assert args["h2d_bytes"] == sum(a.nbytes for a in arrays)
-        assert args["arrays"] == len(arrays)
+        slots = [np.stack(slot) for slot in zip(
+            *(gotoh.encode(**kw).source.arrays for kw in payloads))]
+        assert args["h2d_bytes"] == sum(a.nbytes for a in slots)
+        assert args["arrays"] == len(slots) == 1
+        assert args["source_lanes"] == _LANES
     fetches = _named(events, "dp.fetch")
     assert len(fetches) == _ROUNDS
     assert all(args["d2h_bytes"] > 0 for *_, args in fetches)

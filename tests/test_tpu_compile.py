@@ -140,7 +140,22 @@ def test_kernel_compiles_for_v5e(case, one_chip):
             ((len(CKY_RULES),), f32), ((4, 32), f32))
 
 
-@pytest.mark.parametrize("route", ["kernel_grid", "kernel_tiled_wavefront"])
+def _gotoh_source_program(meta):
+    """The drain program of a gotoh bucket that carries plane sources:
+    the planes are built from each lane's packed int32 vector."""
+    from repro.dp.problem import plane_builder
+
+    build = plane_builder("gotoh")
+    return jax.vmap(lambda *a: ops.grid_blocked_with_args(build(a, meta),
+                                                          meta))
+
+
+#: a gotoh source vector at GOTOH's size: symbols, 4 scores, 2 gap edges
+GOTOH_SOURCE = ((2 * (GOTOH[0] - 1 + GOTOH[1] - 1) + 4,), jnp.int32)
+
+
+@pytest.mark.parametrize("route", ["kernel_grid", "kernel_grid_source",
+                                   "kernel_tiled_wavefront"])
 def test_vmapped_drain_program_compiles(route, one_chip, pallas_mode):
     """A batch-8 drain program as the backends build it: the route's
     ``ops`` entry point vmapped over the stacked bucket."""
@@ -148,6 +163,10 @@ def test_vmapped_drain_program_compiles(route, one_chip, pallas_mode):
         meta = _grid_meta(*GOTOH)
         shapes = [((8,) + s, d) for s, d in _grid_shapes(*GOTOH)]
         fn = jax.vmap(lambda *a: ops.grid_blocked_with_args(a, meta))
+    elif route == "kernel_grid_source":
+        meta = _grid_meta(*GOTOH)
+        shapes = [((8,) + GOTOH_SOURCE[0], GOTOH_SOURCE[1])]
+        fn = _gotoh_source_program(meta)
     else:
         shapes = [((8, num_cells(512), 511), f32)]
         fn = jax.vmap(lambda w: ops.mcm_tiled_fused(w, 512))
@@ -158,17 +177,28 @@ def test_sharded_drain_program_compiles(topo, pallas_mode):
     """The four-chip drain: ``ShardContext.wrap`` shard_maps the vmapped
     grid program over a 4-device mesh; each device runs the kernel on its
     shard of the bucket."""
+    meta = _grid_meta(*GOTOH)
+    _compile_sharded(topo, jax.vmap(
+        lambda *a: ops.grid_blocked_with_args(a, meta)), _grid_shapes(*GOTOH))
+
+
+def test_sharded_source_drain_program_compiles(topo, pallas_mode):
+    """The four-chip drain of a bucket sent as plane sources: each device
+    builds its shard's planes, then runs the kernel."""
+    _compile_sharded(topo, _gotoh_source_program(_grid_meta(*GOTOH)),
+                     [GOTOH_SOURCE])
+
+
+def _compile_sharded(topo, call, shapes):
     from jax.sharding import Mesh
 
     from repro.dp.sharding import BATCH_AXIS, ShardContext
 
     mesh = Mesh(np.array(topo.devices), (BATCH_AXIS,))
     ctx = ShardContext(mesh=mesh)
-    meta = _grid_meta(*GOTOH)
-    call = jax.vmap(lambda *a: ops.grid_blocked_with_args(a, meta))
     shard = NamedSharding(mesh, PartitionSpec(BATCH_AXIS))
     args = [jax.ShapeDtypeStruct((8,) + s, d, sharding=shard)
-            for s, d in _grid_shapes(*GOTOH)]
+            for s, d in shapes]
     compiled = ctx.wrap(call).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert len(compiled.output_shardings[0].device_set) == 4
