@@ -82,19 +82,32 @@ def stack_bucket(arrays, sharding=None):
     return sharding.place(np.stack([np.asarray(a) for a in arrays]))
 
 
+def _shard_bytes(arrays) -> dict:
+    """``shards`` (the devices that hold part of ``arrays``) and
+    ``h2d_bytes_per_shard`` (the most bytes of them one device holds), read
+    from the placed arrays themselves."""
+    held = {}
+    for a in arrays:
+        for shard in a.addressable_shards:
+            held[shard.device] = held.get(shard.device, 0) + shard.data.nbytes
+    return {"shards": len(held), "h2d_bytes_per_shard": max(held.values())}
+
+
 def stack_slots(specs, lane_arrays: Callable, sharding=None) -> tuple:
     """The drain's host→device copy: ``lane_arrays(spec)`` gives each
     lane's host arrays, one per program argument, and each argument's
     lanes are stacked with :func:`stack_bucket`. Runs under the profiler
     span ``dp.stack``, with the bytes and the number of host arrays it
-    sends."""
+    sends, and how they lie over the devices (:func:`_shard_bytes`)."""
     with _telemetry.trace_span("dp.stack") as span:
         lanes = [lane_arrays(s) for s in specs]
+        stacked = tuple(stack_bucket(slot, sharding) for slot in zip(*lanes))
         if _telemetry.tracing():
             span.set_metadata(
                 h2d_bytes=sum(a.nbytes for arrs in lanes for a in arrs),
-                arrays=sum(len(arrs) for arrs in lanes))
-        return tuple(stack_bucket(slot, sharding) for slot in zip(*lanes))
+                arrays=sum(len(arrs) for arrs in lanes),
+                **_shard_bytes(stacked))
+        return stacked
 
 
 def stack_sources(specs, sharding=None) -> tuple:
@@ -108,12 +121,15 @@ def stack_sources(specs, sharding=None) -> tuple:
     with _telemetry.trace_span("dp.stack") as span:
         slots = [np.stack(slot)
                  for slot in zip(*(s.source.arrays for s in specs))]
+        if sharding is None:
+            stacked = tuple(jnp.asarray(a) for a in slots)
+        else:
+            stacked = tuple(sharding.place(a) for a in slots)
         if _telemetry.tracing():
             span.set_metadata(h2d_bytes=sum(a.nbytes for a in slots),
-                              arrays=len(slots), source_lanes=len(specs))
-        if sharding is None:
-            return tuple(jnp.asarray(a) for a in slots)
-        return tuple(sharding.place(a) for a in slots)
+                              arrays=len(slots), source_lanes=len(specs),
+                              **_shard_bytes(stacked))
+        return stacked
 
 
 def common_source(specs) -> Optional[str]:
@@ -138,11 +154,13 @@ def launch(program: Callable, *stacked):
 def fetch(*outs) -> list:
     """Copy a drain's device outputs to the host — the drain's sync point,
     so it waits for the device — under the profiler span ``dp.fetch`` with
-    the bytes it copies."""
+    the bytes it copies and the number of devices it gathers from."""
     with _telemetry.trace_span("dp.fetch") as span:
         host = [np.asarray(o) for o in outs]
         if _telemetry.tracing():
-            span.set_metadata(d2h_bytes=sum(h.nbytes for h in host))
+            span.set_metadata(
+                d2h_bytes=sum(h.nbytes for h in host),
+                shards=max(len(o.sharding.device_set) for o in outs))
     return host
 
 

@@ -230,7 +230,7 @@ class DPEngine:
         return pool[0], False
 
     # -- drain internals (regime + execution hooks) ------------------------
-    # ``ShardedDPEngine`` (repro.dp.sharding) overrides these three to run
+    # ``ShardedDPEngine`` (repro.dp.sharding) overrides these to run
     # batchable drains over a device mesh and key their observations under
     # the ("shard", ndev) regime; everything else in step() is shared.
     def _batch_regime(self, reconstruct: bool) -> tuple:
@@ -253,6 +253,13 @@ class DPEngine:
         if backend.batch_run is None:
             return self._loop_regime(reconstruct)
         return self._batch_regime(reconstruct)
+
+    def _layout(self, backend, spec0: Spec, reconstruct: bool,
+                lanes: int) -> tuple:
+        """``(shards, pad_lanes)`` of a drain of ``lanes`` lanes on
+        ``backend``: the devices it is split over and the pad lanes that
+        split adds (one device, none, here)."""
+        return 1, 0
 
     def _run_bucket(self, backend, specs, reconstruct: bool):
         """Execute one routed bucket; returns
@@ -451,7 +458,11 @@ class DPEngine:
             cold = (warm_key not in self._warmed
                     or _backends.TRACE_COUNT != traces_before)
             _backends.lru_put(self._warmed, warm_key, True, _ROUTE_STATE_MAX)
-            drain_span.set_metadata(cold=int(cold))
+            if _telemetry.tracing():
+                shards, pad = self._layout(chosen, specs[0], reconstruct,
+                                           len(uniq_specs))
+                drain_span.set_metadata(cold=int(cold), shards=shards,
+                                        pad_lanes=pad)
             if drain_rep is not None:
                 drain_rep.cold = cold
                 drain_rep.explored = explored

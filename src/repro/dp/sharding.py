@@ -102,22 +102,27 @@ class ShardContext:
         replicating the last spec (a real instance, so every lane runs the
         ordinary program — no NaN/garbage hazards). Returns
         ``(padded_specs, n_pad)``; callers slice the pad lanes away."""
-        b = len(specs)
-        target = -(-b // self.ndev) * self.ndev
-        return list(specs) + [specs[-1]] * (target - b), target - b
+        n_pad = self.pad_lanes(len(specs))
+        return list(specs) + [specs[-1]] * n_pad, n_pad
+
+    def pad_lanes(self, lanes: int) -> int:
+        """How many pad lanes :meth:`pad` adds to a bucket of ``lanes``."""
+        return -lanes % self.ndev
 
     def place(self, arr):
         """device_put a stacked bucket with its batch dim sharded over the
         mesh — built via the rule-based helpers in
-        ``repro.runtime.sharding`` (the "bucket" logical axis)."""
+        ``repro.runtime.sharding`` (the "bucket" logical axis). Runs under
+        the profiler span ``dp.place``, inside the drain's ``dp.stack``."""
         import jax
 
         from repro.runtime import sharding as _rt
 
-        axes = ("bucket",) + (None,) * (arr.ndim - 1)
-        rules = {"bucket": [self.axis], None: [None]}
-        ns = _rt.named_sharding(self.mesh, arr.shape, axes, rules)
-        return jax.device_put(arr, ns)
+        with _telemetry.trace_span("dp.place"):
+            axes = ("bucket",) + (None,) * (arr.ndim - 1)
+            rules = {"bucket": [self.axis], None: [None]}
+            ns = _rt.named_sharding(self.mesh, arr.shape, axes, rules)
+            return jax.device_put(arr, ns)
 
     def wrap(self, call):
         """``shard_map`` a vmapped batch callable over the batch axis: each
@@ -163,6 +168,11 @@ class ShardedDPEngine(DPEngine):
 
     def _loop_regime(self, reconstruct: bool) -> tuple:
         return super()._batch_regime(reconstruct)
+
+    def _layout(self, backend, spec0, reconstruct: bool, lanes: int) -> tuple:
+        if not self._will_shard(backend, spec0, reconstruct):
+            return super()._layout(backend, spec0, reconstruct, lanes)
+        return self.ctx.ndev, self.ctx.pad_lanes(lanes)
 
     def _obs_suffix(self, backend, spec0, reconstruct: bool) -> tuple:
         """The regime this drain will actually execute under: sharded for

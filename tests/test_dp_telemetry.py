@@ -440,7 +440,7 @@ def test_capture_records_every_span(gotoh_capture):
     assert [args["tid"] for *_, args in _named(events, "dp.submit")] == \
         list(range(_ROUNDS * _LANES))
     assert all(args == {"lanes": _LANES, "unique": _LANES,
-                        "cold": int(i == 0)}
+                        "cold": int(i == 0), "shards": 1, "pad_lanes": 0}
                for i, (*_, args) in enumerate(_named(events, "dp.drain")))
 
 
@@ -460,7 +460,7 @@ def test_capture_counts_host_to_device_bytes(gotoh_capture):
     arrays it sends: gotoh lanes carry a plane source, so each source slot
     goes as one array of every lane's slot, counted here from the
     payloads; ``source_lanes`` counts the lanes whose planes the program
-    builds."""
+    builds. On one device the one shard receives every byte."""
     events, rounds = gotoh_capture
     gotoh = dp.get_problem("gotoh")
     stacks = _named(events, "dp.stack")
@@ -471,9 +471,13 @@ def test_capture_counts_host_to_device_bytes(gotoh_capture):
         assert args["h2d_bytes"] == sum(a.nbytes for a in slots)
         assert args["arrays"] == len(slots) == 1
         assert args["source_lanes"] == _LANES
+        assert args["shards"] == 1
+        assert args["h2d_bytes_per_shard"] == args["h2d_bytes"]
+    assert not _named(events, "dp.place")
     fetches = _named(events, "dp.fetch")
     assert len(fetches) == _ROUNDS
-    assert all(args["d2h_bytes"] > 0 for *_, args in fetches)
+    assert all(args["d2h_bytes"] > 0 and args["shards"] == 1
+               for *_, args in fetches)
 
 
 def test_capture_counts_compiles_on_first_drain_only(gotoh_capture):
