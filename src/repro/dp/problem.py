@@ -1,7 +1,8 @@
 """Declarative DP problem specs — the contract between the problem zoo and
 the solver backends (DESIGN.md §3).
 
-A *spec* is the canonical, fully-materialized form of one problem instance.
+A *spec* is the canonical form of one problem instance: fully materialized,
+or, for a grid with a plane source, the source its planes derive from.
 Spec classes form an open **family protocol**: each family (a dataclass with
 a ``family`` tag) registers itself via :func:`register_family` and carries
 every family-specific behaviour as hooks on the class — shape-key tagging
@@ -722,21 +723,37 @@ class TriangularSpec:
                              self.min_prefix_len())[0]
 
 
-#: plane builders by name: ``build(arrays, meta) -> device_arrays()``
+@dataclasses.dataclass(frozen=True)
+class PlaneBuilder:
+    """How a :class:`PlaneSource` expands into an antidiag grid's planes.
+    ``device(arrays, meta)`` is pure jnp and returns ``device_arrays()``
+    inside a batch program; ``host(arrays, meta)`` is numpy and returns
+    ``(weights, init, init_mask)`` as the spec holds them; both give the
+    same planes bit for bit. ``shapes(meta)`` gives each source array's
+    ``(shape, dtype)``."""
+
+    device: Callable
+    host: Callable
+    shapes: Callable
+
+
+#: plane builders by name
 _PLANE_BUILDERS: dict = {}
 
 
-def register_plane_builder(name: str, build: Callable) -> Callable:
-    """Register the pure-jnp builder that a :class:`PlaneSource` named
-    ``name`` is expanded with inside a batch program."""
+def register_plane_builder(name: str, device: Callable, host: Callable,
+                           shapes: Callable) -> None:
+    """Register the builders that a :class:`PlaneSource` named ``name`` is
+    expanded with: on the device inside a batch program, on the host when
+    a host reader asks for the planes."""
     if name in _PLANE_BUILDERS:
         raise ValueError(f"duplicate plane builder {name!r}")
-    _PLANE_BUILDERS[name] = build
-    return build
+    _PLANE_BUILDERS[name] = PlaneBuilder(device, host, shapes)
 
 
 def plane_builder(name: str) -> Callable:
-    return _PLANE_BUILDERS[name]
+    """The device (jnp) builder of the source ``name``."""
+    return _PLANE_BUILDERS[name].device
 
 
 @dataclasses.dataclass(frozen=True)
@@ -744,10 +761,32 @@ class PlaneSource:
     """Compact form of an antidiag grid's planes (DESIGN.md §9): the
     registered builder ``builder`` maps ``arrays`` (small per-instance host
     arrays whose shapes follow from the spec's ``static_meta()``) and the
-    meta to the spec's ``device_arrays()``, bit for bit."""
+    meta to the spec's planes, bit for bit."""
 
     builder: str
     arrays: tuple
+
+
+class _SourcedPlane:
+    """A plane field of :class:`GridSpec` (``weights``, ``init``,
+    ``init_mask``). A spec given a :class:`PlaneSource` and no planes
+    holds none; the first read of any of the three builds them all on the
+    host (:meth:`GridSpec._build_planes`) and keeps them on the spec."""
+
+    def __set_name__(self, owner, name):
+        self.slot = "_" + name
+
+    def __get__(self, spec, owner=None):
+        if spec is None:
+            return None                       # the dataclass default
+        value = spec.__dict__.get(self.slot)
+        if value is None and spec.source is not None:
+            spec._build_planes()
+            value = spec.__dict__[self.slot]
+        return value
+
+    def __set__(self, spec, value):
+        spec.__dict__[self.slot] = value
 
 
 @dataclasses.dataclass(frozen=True)
@@ -781,8 +820,10 @@ class GridSpec:
 
     ``source`` (optional, antidiag): a :class:`PlaneSource` from which a
     batch program builds ``device_arrays()`` on the device instead of
-    receiving them. The planes stay the spec's content — digests, host
-    routes and streaming read them — and any spec derived from other
+    receiving them. A sourced spec is its source: the digest hashes the
+    source, not the planes, and ``weights``/``init``/``init_mask`` are
+    derived — built on the host on first read, when a host route,
+    streaming or ``device_arrays()`` asks. Any spec derived from other
     planes drops the source.
     """
 
@@ -793,10 +834,10 @@ class GridSpec:
     planes: int = 1
     moves: tuple = ()
     rules: tuple = ()
-    weights: Optional[np.ndarray] = None
+    weights: Optional[np.ndarray] = _SourcedPlane()
     rule_weights: Optional[np.ndarray] = None
-    init: Optional[np.ndarray] = None
-    init_mask: Optional[np.ndarray] = None
+    init: Optional[np.ndarray] = _SourcedPlane()
+    init_mask: Optional[np.ndarray] = _SourcedPlane()
     source: Optional[PlaneSource] = dataclasses.field(default=None,
                                                       compare=False)
 
@@ -839,6 +880,9 @@ class GridSpec:
                 if di < 0 or dj < 0 or di + dj < 1:
                     raise ValueError(f"move {m} must step strictly forward "
                                      "(di, dj >= 0, di + dj >= 1)")
+            if self.source is not None:
+                self._check_source()
+                return
             shape = (len(self.moves), self.rows, self.cols)
             if self.weights is None or self.weights.shape != shape:
                 raise ValueError(f"weights must be {shape}, got "
@@ -852,6 +896,8 @@ class GridSpec:
                 raise ValueError("cell (0, 0) must be preset on every plane "
                                  "(no move can reach it)")
         else:
+            if self.source is not None:
+                raise ValueError("only antidiag grids take a plane source")
             if self.moves:
                 raise ValueError("spandiag grids take rules, not shift moves")
             if not self.rules:
@@ -867,8 +913,44 @@ class GridSpec:
             if self.init is None or self.init.shape != (self.planes, self.rows):
                 raise ValueError(f"init must be ({self.planes}, {self.rows})")
 
+    def _check_source(self) -> None:
+        """A sourced spec is checked by its source, without its planes."""
+        build = _PLANE_BUILDERS.get(self.source.builder)
+        if build is None:
+            raise ValueError(f"unknown plane builder {self.source.builder!r}")
+        want = tuple((tuple(shape), np.dtype(dtype))
+                     for shape, dtype in build.shapes(self.static_meta()))
+        got = tuple((np.shape(a), np.asarray(a).dtype)
+                    for a in self.source.arrays)
+        if got != want:
+            raise ValueError(f"{self.source.builder!r} source arrays must be "
+                             f"{want}, got {got}")
+
+    def _build_planes(self) -> None:
+        """Build a sourced spec's missing planes on the host, under the
+        profiler span ``dp.planes``, and keep them on the spec."""
+        from repro.dp import telemetry
+
+        with telemetry.trace_span("dp.planes"):
+            built = _PLANE_BUILDERS[self.source.builder].host(
+                self.source.arrays, self.static_meta())
+        for name, plane in zip(("_weights", "_init", "_init_mask"), built):
+            if self.__dict__.get(name) is None:
+                self.__dict__[name] = plane
+
     # --- family protocol hooks ---------------------------------------------
     def digest_into(self, h) -> None:
+        """A sourced spec hashes its builder, structure and source arrays
+        under its own tag: the builder is a pure function of them, so
+        equal digests still mean bit-equal planes. Any other spec hashes
+        its planes."""
+        if self.source is not None:
+            h.update(b"grid-source")
+            h.update(self.source.builder.encode())
+            h.update(repr(self.static_meta()).encode())
+            for a in self.source.arrays:
+                _hash_array(h, a)
+            return
         h.update(b"grid")
         h.update(self.schedule.encode())
         h.update(self.op.encode())
@@ -1338,12 +1420,37 @@ def spec_digest(spec: Spec) -> str:
     contract: ``extract`` and ``decode`` read only (table, args, spec, path),
     all functions of the spec, so equal digests imply bit-equal Answers.
     A problem whose answer depended on payload data *outside* its encoded
-    spec would break this invariant (DESIGN.md §7) — encode() must
-    materialize everything answer-relevant. Hashing is a family hook
-    (``digest_into``) so new families join the contract by implementing it."""
+    spec would break this invariant (DESIGN.md §7) — the digest must cover
+    everything answer-relevant. It need not hash derived content: a grid
+    spec with a :class:`PlaneSource` hashes the source, from which its
+    planes are built bit for bit, and not the planes. Hashing is a family
+    hook (``digest_into``) so new families join the contract by
+    implementing it."""
     h = hashlib.sha256()
     spec.digest_into(h)
     return h.hexdigest()
+
+
+class _CountingHash:
+    """SHA-256 that counts the bytes it is fed."""
+
+    def __init__(self):
+        self._h = hashlib.sha256()
+        self.nbytes = 0
+
+    def update(self, data: bytes) -> None:
+        self.nbytes += len(data)
+        self._h.update(data)
+
+    def hexdigest(self) -> str:
+        return self._h.hexdigest()
+
+
+def spec_digest_bytes(spec: Spec) -> tuple:
+    """``(spec_digest(spec), the number of bytes it hashes)``."""
+    h = _CountingHash()
+    spec.digest_into(h)
+    return h.hexdigest(), h.nbytes
 
 
 # --- reconstruction vocabulary ---------------------------------------------

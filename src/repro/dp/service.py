@@ -52,7 +52,7 @@ from repro.dp import registry as _registry
 from repro.dp import streaming as _streaming
 from repro.dp import telemetry as _telemetry
 from repro.dp.engine import DPEngine
-from repro.dp.problem import Answer, Spec, spec_digest
+from repro.dp.problem import Answer, Spec, spec_digest, spec_digest_bytes
 
 _log = _telemetry.get_logger("service")
 
@@ -288,8 +288,14 @@ class DPService:
         if chain_full is not None:
             digest = chain_full
         else:
-            with _telemetry.trace_span("dp.digest"):
-                digest = spec_digest(spec)
+            with _telemetry.trace_span("dp.digest") as dspan:
+                if _telemetry.tracing():
+                    digest, nbytes = spec_digest_bytes(spec)
+                    dspan.set_metadata(
+                        sourced=int(getattr(spec, "source", None) is not None),
+                        digest_bytes=nbytes)
+                else:
+                    digest = spec_digest(spec)
         now = time.monotonic()
         hit = strip_solution = None
         if serve is None:

@@ -698,11 +698,33 @@ _GOTOH_MOVES = (
 
 def _gotoh_encode(x, y, match=2.0, mismatch=-1.0, gap_open=-3.0,
                   gap_extend=-1.0):
+    """A sourced spec for int symbols (its planes are built on first host
+    read), a spec with its planes for any other."""
     x, y = np.asarray(x), np.asarray(y)
     m, c = len(x), len(y)
     if m < 1 or c < 1:
         raise ValueError("gotoh needs non-empty sequences")
-    R, C = m + 1, c + 1
+    scores = np.asarray((match, mismatch, gap_open, gap_extend),
+                        np.float64).astype(np.float32)
+    # the gap-ramp edges init[1, 1:, 0] and init[2, 0, 1:]
+    up = (gap_open + gap_extend * np.arange(m)).astype(np.float32)
+    left = (gap_open + gap_extend * np.arange(c)).astype(np.float32)
+    source = _gotoh_source(x, y, scores, up, left)
+    planes = {} if source is not None else dict(zip(
+        ("weights", "init", "init_mask"),
+        _gotoh_host_planes(x, y, scores, up, left)))
+    spec = GridSpec(rows=m + 1, cols=c + 1, op="max", schedule="antidiag",
+                    planes=3, moves=_GOTOH_MOVES, source=source, **planes)
+    spec.validate()
+    return spec
+
+
+def _gotoh_host_planes(x, y, scores, up, left):
+    """gotoh's weights, init and init_mask on the host, from the symbols,
+    the float32 scores (match, mismatch, open, extend) and the float32
+    gap-ramp edges."""
+    match, mismatch, gap_open, gap_extend = scores
+    R, C = len(x) + 1, len(y) + 1
     w = np.full((7, R, C), _NEG, dtype=np.float32)
     s = np.where(x[:, None] == y[None, :], match, mismatch)
     w[0, 1:, 1:] = w[1, 1:, 1:] = w[2, 1:, 1:] = s
@@ -714,33 +736,43 @@ def _gotoh_encode(x, y, match=2.0, mismatch=-1.0, gap_open=-3.0,
     mask = np.zeros((3, R, C), dtype=bool)
     mask[:, 0, :] = mask[:, :, 0] = True
     init[0, 0, 0] = 0.0
-    init[1, 1:, 0] = gap_open + gap_extend * np.arange(m)
-    init[2, 0, 1:] = gap_open + gap_extend * np.arange(c)
-    spec = GridSpec(rows=R, cols=C, op="max", schedule="antidiag", planes=3,
-                    moves=_GOTOH_MOVES, weights=w, init=init, init_mask=mask,
-                    source=_gotoh_source(
-                        x, y, (match, mismatch, gap_open, gap_extend), init))
-    spec.validate()
-    return spec
+    init[1, 1:, 0] = up
+    init[2, 0, 1:] = left
+    return w, init, mask
 
 
-def _gotoh_source(x, y, scores, init):
+def _gotoh_source(x, y, scores, up, left):
     """The planes' compact form, one int32 vector: the symbols, then the
     bits of the float32 scores (match, mismatch, open, extend) and of the
-    two gap-ramp edges as the host computed them. ``None`` for symbols the
-    int32 cast would not keep distinct."""
+    two gap-ramp edges. ``None`` for symbols the int32 cast would not keep
+    distinct."""
     if not all(a.dtype.kind in "biu" and a.min() >= _I32.min
                and a.max() <= _I32.max for a in (x, y)):
         return None
-    bits = np.concatenate([np.asarray(scores, np.float64).astype(np.float32),
-                           init[1, 1:, 0], init[2, 0, 1:]]).view(np.int32)
+    bits = np.concatenate([scores, up, left]).view(np.int32)
     return PlaneSource("gotoh", (np.concatenate(
         [x.astype(np.int32), y.astype(np.int32), bits]),))
 
 
+def _gotoh_source_shapes(meta):
+    m, c = meta[3] - 1, meta[4] - 1
+    return (((2 * (m + c) + 4,), np.int32),)
+
+
+def _gotoh_planes_np(arrays, meta):
+    """The host form of :func:`_gotoh_planes`: the spec's weights, init
+    and init_mask from its source."""
+    packed, = arrays
+    m, c = meta[3] - 1, meta[4] - 1
+    f = packed[m + c:].view(np.float32)
+    return _gotoh_host_planes(packed[:m], packed[m:m + c], f[:4],
+                              f[4:4 + m], f[4 + m:])
+
+
 def _gotoh_planes(arrays, meta):
-    """``_gotoh_encode``'s weights, init and float mask from its source,
-    in jnp: selects only, so every entry is the host's bit for bit."""
+    """The spec's weights, init and float mask from its source, in jnp:
+    selects only, so every entry is :func:`_gotoh_planes_np`'s bit for
+    bit."""
     import jax
     import jax.numpy as jnp
 
@@ -774,7 +806,8 @@ def _gotoh_planes(arrays, meta):
     return w, init, mask
 
 
-register_plane_builder("gotoh", _gotoh_planes)
+register_plane_builder("gotoh", _gotoh_planes, host=_gotoh_planes_np,
+                       shapes=_gotoh_source_shapes)
 
 
 def _gotoh_oracle(x, y, match=2.0, mismatch=-1.0, gap_open=-3.0,

@@ -11,6 +11,7 @@ import pytest
 
 from repro import dp
 from repro.dp import backends, telemetry
+from repro.dp.problem import spec_digest_bytes
 
 
 @pytest.fixture(autouse=True)
@@ -478,6 +479,21 @@ def test_capture_counts_host_to_device_bytes(gotoh_capture):
     assert len(fetches) == _ROUNDS
     assert all(args["d2h_bytes"] > 0 and args["shards"] == 1
                for *_, args in fetches)
+
+
+def test_capture_digests_gotoh_by_its_source(gotoh_capture):
+    """Every gotoh ``dp.digest`` hashes the pair's plane source
+    (``sourced`` 1; ``digest_bytes`` a few hundred, not the planes' tens
+    of kilobytes), and no sourced spec's planes are built on the host: the
+    capture holds no ``dp.planes``."""
+    events, rounds = gotoh_capture
+    gotoh = dp.get_problem("gotoh")
+    want = [spec_digest_bytes(gotoh.encode(**kw))[1]
+            for payloads in rounds for kw in payloads]
+    assert [args for *_, args in _named(events, "dp.digest")] == \
+        [{"sourced": 1, "digest_bytes": n} for n in want]
+    assert all(n < 1000 for n in want)
+    assert not _named(events, "dp.planes")
 
 
 def test_capture_counts_compiles_on_first_drain_only(gotoh_capture):
