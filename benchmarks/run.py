@@ -12,8 +12,7 @@ import argparse
 
 
 def main(smoke: bool = False, check_dispatch: bool = False) -> None:
-    from benchmarks import (dp_service_bench, dp_zoo_bench, mcm_bench,
-                            roofline, table1_sdp)
+    from benchmarks import dp_zoo_bench, mcm_bench, roofline, table1_sdp
 
     if smoke:
         print("# smoke mode: reduced sizes, correctness checks only")
@@ -35,16 +34,6 @@ def main(smoke: bool = False, check_dispatch: bool = False) -> None:
     else:
         dp_zoo_bench.run(calibrate=check_dispatch,
                          check_dispatch=check_dispatch)
-    print("# DP service — sharded continuous-batching serving tier "
-          "(DESIGN.md §7)")
-    # smoke: the streaming leg shrinks to a geometry that still extends by
-    # <10% per append but keeps cold-solve warm-up cheap
-    if smoke:
-        dp_service_bench.run(out_path="", n_requests=64, check_perf=False,
-                             streaming_cfg=dict(rows=256, base=512, k=32,
-                                                n_appends=3))
-    else:
-        dp_service_bench.run()
     print("# Roofline — dry-run derived terms (EXPERIMENTS.md §Roofline)")
     roofline.run()
 

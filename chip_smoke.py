@@ -270,11 +270,9 @@ def references(reqs: list) -> list:
 
 
 def kernel_counters(mode: str) -> dict:
-    from repro.dp import telemetry
+    from repro.kernels import ops
 
-    counters = telemetry.REGISTRY.counters()
-    return {fn: counters.get(f"dp_kernel_{fn}_{mode}_total", 0.0)
-            for fn in KERNEL_ENTRIES}
+    return {fn: ops.ENTRY_COUNTS.get((fn, mode), 0) for fn in KERNEL_ENTRIES}
 
 
 def check_routes(routes: dict) -> None:
@@ -418,13 +416,10 @@ def main(argv=None) -> int:
     print(f"# compilation cache: {use_persistent_cache()}")
     import jax
 
-    from repro.dp import telemetry
-
     dev = require_tpu()
     if len(jax.devices()) != args.chips:
         raise SmokeFailure(f"--chips {args.chips} but JAX sees "
                            f"{len(jax.devices())} devices")
-    telemetry.configure("basic")
     sizes = Sizes()
     if args.chips == 1:
         info = one_chip(sizes, args.seed)

@@ -2,9 +2,8 @@
 
 Mixed-problem traffic through submit/poll handles — priorities, deadlines,
 the content-digest answer cache, intra-drain dedup, and (with more than one
-visible device) sharded bucket drains. Runs with telemetry in ``spans``
-mode (DESIGN.md §8), with a request's timestamped span, the per-phase
-latency breakdown, and a Prometheus excerpt.
+visible device) sharded bucket drains, and the counts the service and the
+engine keep in their ``stats`` (DESIGN.md §8).
 
 The tour ends with a streaming session (DESIGN.md §11): one alignment
 instance grown a few columns at a time through ``open_session/append``,
@@ -22,15 +21,10 @@ import time
 import numpy as np
 
 from repro import dp
-from repro.dp import telemetry
 
 
 def main() -> None:
     import jax
-
-    # normally driven by REPRO_TELEMETRY={off,basic,spans,profile}; the
-    # tour opts in explicitly so the walkthrough below always has data
-    telemetry.configure("spans")
 
     ndev = jax.device_count()
     svc = dp.DPService(max_batch=16)
@@ -93,32 +87,14 @@ def main() -> None:
         print(f"  {regime:24s} {len(rows)} shape(s), measured picks: "
               f"{', '.join(sorted(picks))}")
 
-    # -- telemetry walkthrough (DESIGN.md §8) -----------------------------
-    # 1. every non-cached result carries its span: the request's
-    #    timestamped lifecycle and the per-phase attribution derived from it
-    spanned = next(r for r in done if r.span is not None
-                   and "solved" in r.span.event_names())
-    print(f"\nspan of tid {spanned.tid} ({spanned.problem} via "
-          f"{spanned.span.meta.get('backend')}):")
-    t0 = spanned.span.events[0][1]
-    for name, t in spanned.span.events:
-        print(f"  {(t - t0) * 1e3:9.3f} ms  {name}")
-    print("  phases: " + ", ".join(
-        f"{k}={v:.3f}ms" for k, v in spanned.span.phases().items()))
-
-    # 2. the registry aggregates the same attribution across ALL requests
-    print("\nper-phase latency quantiles (registry histograms):")
-    for name, h in sorted(telemetry.REGISTRY.histograms().items()):
-        if name.startswith("dp_service_") and h.count:
-            print(f"  {name:28s} n={h.count:4d} p50={h.quantile(0.5):8.3f} "
-                  f"p99={h.quantile(0.99):8.3f} ms")
-
-    # 3. exporters
-    prom = telemetry.to_prometheus().splitlines()
-    print(f"prometheus export: {len(prom)} lines, e.g.")
-    for line in prom[:4]:
-        print(f"  {line}")
-    # telemetry.save_snapshot("telemetry.json") dumps all of the above
+    # -- the counts (DESIGN.md §8) ----------------------------------------
+    # the service's and the engine's stats are the run's counts; where the
+    # time went is the profiler's to say (wrap the traffic in
+    # jax.profiler.start_trace/stop_trace to record the dp.* spans)
+    print("\nservice stats: " + ", ".join(
+        f"{k}={v}" for k, v in svc.stats.items() if v))
+    print("engine stats:  " + ", ".join(
+        f"{k}={v}" for k, v in svc.engine.stats.items() if v))
 
     # -- streaming session (DESIGN.md §11) --------------------------------
     # one growing alignment: y gains 24 columns per append; the service

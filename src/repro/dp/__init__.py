@@ -31,8 +31,9 @@ Layers (DESIGN.md §3, §5):
                 deadlines/priorities, content-digest answer cache,
                 streaming sessions (open_session/append/close_session),
                 the continuous scheduling loop (DESIGN.md §7, §11)
-  telemetry   — request spans, metrics registry, profiler spans, exporters
-                (REPRO_TELEMETRY={off,basic,spans}; DESIGN.md §8)
+  telemetry   — the dp.* profiler spans (the one timing layer) and the
+                repro.dp logger (DESIGN.md §8); the counts live in the
+                engine's and the service's ``stats``
 
 Quickstart::
 
@@ -61,14 +62,13 @@ from repro.dp.registry import problems  # noqa: F401
 from repro.dp.service import AdmissionError, DPService, ServiceResult, Session  # noqa: F401
 from repro.dp.sharding import ShardContext, ShardedDPEngine  # noqa: F401
 from repro.dp.streaming import PrefixIndex, ResumeToken, resume_solve  # noqa: F401
-from repro.dp.telemetry import Span  # noqa: F401
 from repro.dp import service, sharding, streaming, telemetry  # noqa: F401
 
 __all__ = [
     "AdmissionError", "Answer", "DPEngine", "DPProblem", "DPRequest",
     "DPResponse", "DPService", "GridPath", "GridSpec", "LinearPath",
     "LinearSpec", "PrefixIndex", "ResumeToken", "ServiceResult", "Session",
-    "ShardContext", "ShardedDPEngine", "Span", "Spec", "TriangularPath",
+    "ShardContext", "ShardedDPEngine", "Spec", "TriangularPath",
     "TriangularSpec", "autotune", "backends", "batch_solve",
     "batch_solve_specs", "calibrate", "dispatch", "route", "get_problem",
     "problem_names", "problems", "reconstruct", "registry", "resume_solve",

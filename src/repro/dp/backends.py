@@ -57,7 +57,6 @@ def log_trace(key) -> None:
         TRACE_LOG.append(key)
         if len(TRACE_LOG) > TRACE_LOG_MAX:
             del TRACE_LOG[: len(TRACE_LOG) - TRACE_LOG_MAX]
-    _telemetry.count("dp_backend_traces_total")
 
 
 def drain_trace_log() -> list:

@@ -43,8 +43,6 @@ from repro.dp import routing as _routing
 from repro.dp import telemetry as _telemetry
 from repro.dp.problem import Answer, Spec, spec_digest
 
-_log = _telemetry.get_logger("engine")
-
 #: LRU bound on the engine's per-route bookkeeping (_drains / _warmed) —
 #: endless fresh shapes must not grow process memory (same invariant as the
 #: TRACE_LOG / _BATCH_CACHE bounds). Evicting a _warmed triple just costs
@@ -80,9 +78,6 @@ class DPResponse:
     backend: str
     batch_size: int
     solution: Optional[Answer] = None
-    #: this rid shared another request's solve lane (intra-drain dedup
-    #: fan-out) — telemetry marks its span instead of re-counting work
-    deduped: bool = False
     #: full solved table (read-only), only when the request asked for it
     table: Optional[Any] = None
     #: resolved by a warm-start extend drain rather than a cold solve
@@ -123,11 +118,6 @@ class DPEngine:
                       "explore_dispatches": 0, "feedback_observations": 0,
                       "extend_drains": 0, "extend_requests": 0,
                       "affine_lanes": 0}
-        #: :class:`repro.dp.telemetry.DrainReport` of the most recent
-        #: drain (None below ``basic`` telemetry) — the service reads it to
-        #: attribute span events and per-phase histograms per request
-        self.last_drain = None
-        _telemetry.REGISTRY.register_source("dp_engine", self)
 
     # -- admission ---------------------------------------------------------
     def submit(self, problem: str, reconstruct: bool = False,
@@ -317,11 +307,8 @@ class DPEngine:
         obs_key = uniq[0].spec.shape_key() + _routing.EXTEND_SUFFIX
         routes = [self._extend_route(r, backend) for r in uniq]
         tables, answers, lane_cold = [], [], []
-        with _telemetry.drain_scope(key, routes[0][0].name, len(batch),
-                                    len(uniq)) as drain_rep, \
-                _telemetry.trace_span("dp.drain", lanes=len(batch),
-                                      unique=len(uniq)) as drain_span:
-            extend_ms = 0.0
+        with _telemetry.trace_span("dp.drain", lanes=len(batch),
+                                   unique=len(uniq)) as drain_span:
             for r, (chosen, affine) in zip(uniq, routes):
                 tok = r.resume
                 traces_before = _backends.TRACE_COUNT
@@ -329,10 +316,7 @@ class DPEngine:
                 ext = chosen.run_extend(r.spec, tok.old_len, tok.state())
                 table = r.spec.stitch_extension(tok.prefix_spec,
                                                 tok.prefix_table, ext)
-                t1 = time.perf_counter()
-                _telemetry.add_phase("extend", t0, t1)
-                lane_ms = (t1 - t0) * 1e3
-                extend_ms += lane_ms
+                lane_ms = (time.perf_counter() - t0) * 1e3
                 # same freezing rule as batched drains: dedup fan-out and
                 # the caches share this exact array
                 table.setflags(write=False)
@@ -355,18 +339,15 @@ class DPEngine:
                 else:
                     answers.append(None)
             drain_span.set_metadata(cold=int(any(lane_cold)))
-            if drain_rep is not None:
-                drain_rep.cold = any(lane_cold)
-        self.last_drain = drain_rep
         responses = []
         with _telemetry.trace_span("dp.extract"):
-            for i, r in enumerate(batch):
+            for r in batch:
                 j = lane_of[r.digest]
                 responses.append(DPResponse(
                     rid=r.rid, problem=r.problem,
                     answer=prob.extract(tables[j], r.spec),
                     backend=routes[j][0].name, batch_size=len(batch),
-                    solution=answers[j], deduped=uniq_idx[r.digest] != i,
+                    solution=answers[j],
                     table=tables[j] if r.keep_table else None,
                     extended=True, affine=routes[j][1]))
         if rest:
@@ -381,12 +362,6 @@ class DPEngine:
         self.stats["dedup_hits"] += len(batch) - len(uniq)
         if reconstruct:
             self.stats["host_tracebacks"] += len(uniq)
-        if _telemetry.enabled("basic"):
-            _telemetry.count("dp_engine_extend_drains_total")
-            _telemetry.count("dp_engine_extend_requests_total", len(batch))
-            _telemetry.set_gauge("dp_engine_pending", self.pending())
-            _log.debug("extend drain %r: %d req (%d lanes) in %.3f ms",
-                       key, len(batch), len(uniq), extend_ms)
         return responses
 
     # -- one batched device call ------------------------------------------
@@ -428,21 +403,19 @@ class DPEngine:
             uniq_idx.setdefault(r.digest, i)
         lane_of = {d: j for j, d in enumerate(uniq_idx)}
         uniq_specs = [specs[i] for i in uniq_idx.values()]
+        lanes, unique = len(batch), len(uniq_specs)
 
         obs_key = specs[0].shape_key() + self._obs_suffix(chosen, specs[0],
                                                           reconstruct)
-        warm_key = (chosen.name, obs_key, len(uniq_specs))
-        with _telemetry.drain_scope(key, chosen.name, len(batch),
-                                    len(uniq_specs)) as drain_rep, \
-                _telemetry.trace_span("dp.drain", lanes=len(batch),
-                                      unique=len(uniq_specs)) as drain_span:
+        warm_key = (chosen.name, obs_key, unique)
+        with _telemetry.trace_span("dp.drain", lanes=lanes,
+                                   unique=unique) as drain_span:
             traces_before = _backends.TRACE_COUNT
             t0 = time.perf_counter()
             tables, argss, source, paths = self._run_bucket(
                 chosen, uniq_specs, reconstruct)
             t1 = time.perf_counter()
             solve_ms = (t1 - t0) * 1e3
-            _telemetry.add_phase("solve", t0, t1)
             # dedup fan-out (and the service answer cache) hand the SAME
             # arrays to multiple consumers — freeze them so a caller's
             # in-place edit raises instead of silently corrupting the
@@ -460,28 +433,23 @@ class DPEngine:
             _backends.lru_put(self._warmed, warm_key, True, _ROUTE_STATE_MAX)
             if _telemetry.tracing():
                 shards, pad = self._layout(chosen, specs[0], reconstruct,
-                                           len(uniq_specs))
+                                           unique)
                 drain_span.set_metadata(cold=int(cold), shards=shards,
                                         pad_lanes=pad)
-            if drain_rep is not None:
-                drain_rep.cold = cold
-                drain_rep.explored = explored
             if reconstruct:
                 answers = _reconstruct.reconstruct_batch(
                     prob, uniq_specs, tables, argss, source, paths=paths)
             else:
                 answers = [None] * len(uniq_specs)
-        self.last_drain = drain_rep
         responses = []
         with _telemetry.trace_span("dp.extract"):
-            for i, r in enumerate(batch):
+            for r in batch:
                 j = lane_of[r.digest]
                 responses.append(
                     DPResponse(rid=r.rid, problem=r.problem,
                                answer=prob.extract(tables[j], r.spec),
-                               backend=chosen.name, batch_size=len(batch),
+                               backend=chosen.name, batch_size=lanes,
                                solution=answers[j],
-                               deduped=uniq_idx[r.digest] != i,
                                table=tables[j] if r.keep_table else None))
 
         if rest:
@@ -491,16 +459,15 @@ class DPEngine:
         _backends.lru_put(self._drains, key, self._drains.get(key, 0) + 1,
                           _ROUTE_STATE_MAX)
         self.stats["device_batches"] += 1
-        self.stats["completed"] += len(batch)
-        self.stats["batched_requests"] += len(batch) if len(batch) > 1 else 0
-        self.stats["dedup_hits"] += len(batch) - len(uniq_specs)
+        self.stats["completed"] += lanes
+        self.stats["batched_requests"] += lanes if lanes > 1 else 0
+        self.stats["dedup_hits"] += lanes - unique
         if explored:
             self.stats["explore_dispatches"] += 1
         if self.feedback and not cold:
             # per-instance cost of what the device actually solved — the
             # deduped lane count, not the fan-out count
-            _autotune.observe(chosen.name, obs_key,
-                              solve_ms / len(uniq_specs))
+            _autotune.observe(chosen.name, obs_key, solve_ms / unique)
             self.stats["feedback_observations"] += 1
         if reconstruct:
             # count walks actually executed (the deduped lanes), matching
@@ -508,21 +475,7 @@ class DPEngine:
             # the device-vs-host traceback picture
             counter = ("device_tracebacks" if source == "device"
                        else "host_tracebacks")
-            self.stats[counter] += len(uniq_specs)
-        if _telemetry.enabled("basic"):
-            _telemetry.count("dp_engine_drains_total")
-            _telemetry.count("dp_engine_requests_total", len(batch))
-            _telemetry.count("dp_engine_dedup_fanout_total",
-                             len(batch) - len(uniq_specs))
-            if cold:
-                _telemetry.count("dp_engine_cold_drains_total")
-            _telemetry.observe_ms("dp_engine_batch_size", len(batch),
-                                  buckets=_telemetry.DEFAULT_SIZE_BUCKETS)
-            _telemetry.set_gauge("dp_engine_pending", self.pending())
-            _log.debug("drain %r: %d req (%d unique) via %s in %.3f ms "
-                       "(cold=%s explored=%s)", key, len(batch),
-                       len(uniq_specs), chosen.name, solve_ms, cold,
-                       explored)
+            self.stats[counter] += unique
         return responses
 
     def run(self, backend: Optional[str] = None) -> dict:

@@ -152,13 +152,6 @@ register_knob(Knob(
                 "DP-route knob)"))
 
 register_knob(Knob(
-    name="REPRO_TELEMETRY", kind="choice", what="telemetry mode",
-    choices=("off", "basic", "spans"), default="off",
-    probe="basic",
-    description="telemetry level; observability only — must never change "
-                "routing or results (DESIGN.md §8)"))
-
-register_knob(Knob(
     name="REPRO_LOG", kind="choice", what="log level",
     choices=("off", "error", "warning", "info", "debug"), default="off",
     probe="error",

@@ -27,7 +27,7 @@ Every family-specific step is a hook on the spec class (DESIGN.md §3):
 (traceback entry points), ``traceback_host`` (per-instance walk), and
 ``traceback_program`` (the batched device walk). This module owns only the
 family-agnostic plumbing: admission, caching, start-cell resolution,
-batching, and telemetry.
+batching, and the profiler spans of the walk and the decode.
 
 Traceback programs are cached per shape and append a
 ``("traceback", …)`` entry to ``backends.TRACE_LOG`` at trace time, so
@@ -125,18 +125,11 @@ def reconstruct_batch(prob: DPProblem, specs: Sequence[Spec],
     """Batch assembly. Device-sourced args are walked by ONE vmapped
     traceback program; host-sourced args fall back to host walks; fused
     routes pass the in-launch-walked ``paths`` in and skip the traceback
-    dispatch entirely (the phase still reports, at ~zero ms). The walk
-    and the decode loop each report their start and end as a telemetry
-    phase (``traceback`` / ``decode``) — onto the engine's active drain
-    report when one is open, always into the registry histograms (no-op
-    when telemetry is off) — and run inside the profiler spans
-    ``dp.traceback`` / ``dp.decode``."""
-    import time
-
+    dispatch entirely. The walk and the decode loop run inside the
+    profiler spans ``dp.traceback`` / ``dp.decode``."""
     from repro.dp import telemetry as _telemetry
 
     spec0 = specs[0]
-    t0 = time.perf_counter()
     with _telemetry.trace_span("dp.traceback"):
         if paths is not None:
             paths = list(paths)
@@ -150,10 +143,6 @@ def reconstruct_batch(prob: DPProblem, specs: Sequence[Spec],
             paths = [traceback_host(a, s, start_cell(prob, t, s)
                                     if s.uses_start else -1)
                      for a, s, t in zip(argss, specs, tables)]
-    t1 = time.perf_counter()
-    _telemetry.add_phase("traceback", t0, t1)
     with _telemetry.trace_span("dp.decode"):
-        answers = [reconstruct_one(prob, s, t, a, source, path=p)
-                   for s, t, a, p in zip(specs, tables, argss, paths)]
-    _telemetry.add_phase("decode", t1, time.perf_counter())
-    return answers
+        return [reconstruct_one(prob, s, t, a, source, path=p)
+                for s, t, a, p in zip(specs, tables, argss, paths)]

@@ -38,7 +38,6 @@ from repro.dp import autotune as _autotune
 from repro.dp import backends as _backends
 from repro.dp import reconstruct as _reconstruct
 from repro.dp import registry as _registry
-from repro.dp import telemetry as _telemetry
 from repro.dp.problem import DPProblem, Spec
 
 
@@ -69,7 +68,6 @@ def dispatch(spec_or_problem, reconstruct: bool = False,
     cands = _backends.candidates(spec)
     if not cands:
         raise RuntimeError(f"no backend supports spec {spec.shape_key()}")
-    _telemetry.count("dp_routing_dispatch_total")
     if reconstruct and _reconstruct.supports_args(spec):
         arg_capable = [b for b in cands if b.run_with_args is not None]
         if arg_capable:
@@ -166,7 +164,6 @@ def solve(problem: Union[str, DPProblem], backend: Optional[str] = None,
     b = resolve_backend(spec, backend, reconstruct=True)
     if b.run_fused is not None and _reconstruct.supports_args(spec):
         # fused route: solve + args + traceback walked in ONE dispatch
-        _telemetry.count("dp_routing_fused_total")
         table, args, path = b.run_fused(spec)
         return _reconstruct.reconstruct_one(prob, spec, table, args,
                                             "device", path=path)
@@ -205,7 +202,6 @@ def run_extend(spec: Spec, old_len: int, state, backend=None):
             raise RuntimeError(
                 f"no extend-capable backend for spec {spec.shape_key()}")
         b = cands[0]
-    _telemetry.count("dp_routing_extend_total")
     return b.run_extend(spec, old_len, state)
 
 
@@ -216,14 +212,12 @@ def run_batch(b: _backends.Backend, specs: Sequence[Spec],
     device mesh — only meaningful on batchable routes whose batch size the
     caller already padded to the mesh size."""
     if b.batch_run is not None:
-        _telemetry.count("dp_routing_batch_runs_total")
         if sharding is not None:
             return b.batch_run(list(specs), sharding=sharding)
         return b.batch_run(list(specs))
     # loop fallback: the route has no vmapped batch path, so the "batch"
-    # executes as B singleton device calls — worth counting, it is the
-    # pipeline the engine's batching exists to avoid
-    _telemetry.count("dp_routing_loop_fallback_total")
+    # executes as B singleton device calls — the pipeline the engine's
+    # batching exists to avoid
     return [b.run(s) for s in specs]
 
 
@@ -237,8 +231,6 @@ def run_batch_with_args(b: _backends.Backend, specs: Sequence[Spec],
     specs = list(specs)
     if _reconstruct.supports_args(specs[0]):
         if b.batch_run_fused is not None:
-            _telemetry.count("dp_routing_args_device_total")
-            _telemetry.count("dp_routing_fused_total")
             if sharding is not None:
                 tables, argss, paths = b.batch_run_fused(specs,
                                                          sharding=sharding)
@@ -246,17 +238,14 @@ def run_batch_with_args(b: _backends.Backend, specs: Sequence[Spec],
                 tables, argss, paths = b.batch_run_fused(specs)
             return tables, argss, "device", paths
         if b.batch_run_with_args is not None:
-            _telemetry.count("dp_routing_args_device_total")
             if sharding is not None:
                 tables, argss = b.batch_run_with_args(specs, sharding=sharding)
             else:
                 tables, argss = b.batch_run_with_args(specs)
             return tables, argss, "device", None
         if b.run_with_args is not None:
-            _telemetry.count("dp_routing_args_device_total")
             pairs = [b.run_with_args(s) for s in specs]
             return [t for t, _ in pairs], [a for _, a in pairs], "device", None
-    _telemetry.count("dp_routing_args_host_total")
     tables = run_batch(b, specs)
     argss = [_reconstruct.args_from_table(t, s)
              for t, s in zip(tables, specs)]

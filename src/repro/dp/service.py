@@ -73,10 +73,6 @@ class Ticket:
     priority: int
     deadline: Optional[float]      # absolute time.monotonic() start-by bound
     submitted_at: float
-    #: telemetry timestamps on the ``telemetry.clock`` timebase (set in
-    #: ``basic`` mode and above; 0.0 when telemetry is off)
-    t_enqueued: float = 0.0
-    t_dispatched: float = 0.0
     #: warm-start handle (streaming sessions) — routes into an engine
     #: extend bucket
     resume: Optional[_streaming.ResumeToken] = None
@@ -93,9 +89,9 @@ class Ticket:
 class ServiceResult:
     """Resolution of one ticket. ``status`` is ``"done"`` or ``"expired"``;
     ``cached`` marks answers served from the digest cache without a device
-    call; ``latency_ms`` is submit→resolve wall time. In ``spans``
-    telemetry mode ``span`` carries the request's full timestamped
-    lifecycle (:class:`repro.dp.telemetry.Span`)."""
+    call; ``latency_ms`` is submit→resolve wall time. Where the time went
+    inside a drain is the profiler's to say: the ``dp.*`` spans of
+    :mod:`repro.dp.telemetry` (DESIGN.md §8)."""
 
     tid: int
     problem: str
@@ -105,7 +101,6 @@ class ServiceResult:
     backend: Optional[str] = None
     cached: bool = False
     latency_ms: float = 0.0
-    span: Optional[_telemetry.Span] = None
     #: resolved by a warm-start extend drain (or a full prefix-index hit)
     #: instead of a cold solve
     extended: bool = False
@@ -216,7 +211,7 @@ class DPService:
         #: per-route view; per-regime detail lives in routing_report())
         self.routes: dict = {}
         #: ``shed`` and ``rejected`` are the same count (``shed`` is the
-        #: telemetry-conventional name; ``rejected`` the original); the
+        #: load-shedding name; ``rejected`` the original); the
         #: service invariant is
         #: ``submitted == completed + pending() + expired + shed``
         self.stats = {"submitted": 0, "completed": 0, "cache_hits": 0,
@@ -227,8 +222,6 @@ class DPService:
                       "session_appends": 0,
                       "prefix_hits": 0, "prefix_full_hits": 0,
                       "prefix_misses": 0}
-        #: tid -> live telemetry Span (``spans`` mode only)
-        self._spans: dict = {}
         # -- streaming sessions (DESIGN.md §11): knob-bounded session map
         # plus the cross-session longest-prefix answer cache
         self._next_sid = 0
@@ -236,7 +229,6 @@ class DPService:
         self.session_ttl_ms = _envknobs.read("REPRO_SESSION_TTL_MS")
         self.session_max = _envknobs.read("REPRO_SESSION_MAX")
         self.prefix_index = _streaming.PrefixIndex()
-        _telemetry.REGISTRY.register_source("dp_service", self)
 
     # -- admission ---------------------------------------------------------
     def backlog(self) -> int:
@@ -314,48 +306,30 @@ class DPService:
                     self._cache.move_to_end((prob.name, digest, True))
                     hit, strip_solution = rich, True
         # submitted counts every request that reached admission — including
-        # shed ones — so the §8 invariant
+        # shed ones — so the invariant
         # submitted == completed + pending() + expired + shed always balances
         self.stats["submitted"] += 1
-        span = _telemetry.new_span(self._next_tid, prob.name)
-        if span is not None:
-            span.add("admitted")
         if (hit is None and serve is None
                 and self.backlog() >= self.max_pending):
             self.stats["rejected"] += 1
             self.stats["shed"] += 1
-            _telemetry.count("dp_service_shed_total")
-            if span is not None:
-                span.meta["status"] = "shed"
-                _telemetry.finish_span(span.add("shed"))
             raise AdmissionError(
                 f"backlog full ({self.max_pending} pending); retry later")
         tid = self._next_tid
         self._next_tid += 1
-        _telemetry.count("dp_service_submitted_total")
         if hit is not None or serve is not None:
             if hit is not None:
                 answer = hit.answer
                 solution = None if strip_solution else hit.solution
                 backend_name, extended = hit.backend, False
                 self.stats["cache_hits"] += 1
-                _telemetry.count("dp_service_cache_hits_total")
-                if span is not None:
-                    span.add("cache_hit")
             else:
                 answer, solution, backend_name, extended = serve
-                if span is not None:
-                    span.add("prefix_hit")
             self.stats["completed"] += 1
-            _telemetry.observe_ms("dp_service_latency_ms", 0.0)
-            if span is not None:
-                span.meta.update(status="done", cached=True,
-                                 backend=backend_name)
-                _telemetry.finish_span(span.add("resolved"))
             _backends.lru_put(self._results, tid, ServiceResult(
                 tid=tid, problem=prob.name, status="done", answer=answer,
                 solution=solution, backend=backend_name, cached=True,
-                latency_ms=0.0, span=span, extended=extended, sid=sid),
+                latency_ms=0.0, extended=extended, sid=sid),
                 self.results_max)
             return tid
         self.stats["cache_misses"] += 1
@@ -367,14 +341,9 @@ class DPService:
         ticket = Ticket(
             tid=tid, problem=prob.name, spec=spec, digest=digest,
             reconstruct=reconstruct, priority=priority, deadline=deadline,
-            submitted_at=now,
-            t_enqueued=_telemetry.clock() if _telemetry.enabled() else 0.0,
-            resume=resume, sid=sid, keep_table=keep_table,
+            submitted_at=now, resume=resume, sid=sid, keep_table=keep_table,
             chain_full=chain_full)
         self._backlog.setdefault(key, []).append(ticket)
-        if span is not None:
-            span.add("enqueued", ticket.t_enqueued)
-            self._spans[tid] = span
         return tid
 
     # -- streaming sessions (DESIGN.md §11) --------------------------------
@@ -395,9 +364,7 @@ class DPService:
         while len(self._sessions) > self.session_max:
             self._sessions.popitem(last=False)
             self.stats["sessions_evicted"] += 1
-            _telemetry.count("dp_service_sessions_evicted_total")
         self.stats["sessions_opened"] += 1
-        _telemetry.count("dp_service_sessions_opened_total")
         return sid
 
     def _session(self, sid: int) -> Session:
@@ -415,7 +382,6 @@ class DPService:
                     if s.last_seen < cutoff]:
             del self._sessions[sid]
             self.stats["sessions_expired"] += 1
-            _telemetry.count("dp_service_sessions_expired_total")
 
     def append(self, sid: int, priority: int = 0,
                deadline_ms: Optional[float] = None,
@@ -439,7 +405,6 @@ class DPService:
         s.last_seen = time.monotonic()
         s.appends += 1
         self.stats["session_appends"] += 1
-        _telemetry.count("dp_service_session_appends_total")
         prob = _registry.get(s.problem)
         spec = prob.encode(**payload)
         chain = s.cursor.advance(spec) if s.cursor is not None else None
@@ -454,7 +419,6 @@ class DPService:
         if ent is not None and ent.length == n:
             self.stats["prefix_hits"] += 1
             self.stats["prefix_full_hits"] += 1
-            _telemetry.count("dp_service_prefix_hits_total")
             solution = None
             if reconstruct:
                 _reconstruct.check_reconstructable(prob, spec)
@@ -468,12 +432,10 @@ class DPService:
                      ent.backend, True)
         elif ent is not None:
             self.stats["prefix_hits"] += 1
-            _telemetry.count("dp_service_prefix_hits_total")
             s.extends += 1
             resume = ent.token(affinity=s.affinity)
         else:
             self.stats["prefix_misses"] += 1
-            _telemetry.count("dp_service_prefix_misses_total")
         return self._submit(prob, spec, priority, deadline_ms, reconstruct,
                             resume=resume, sid=sid,
                             keep_table=serve is None and streamable,
@@ -487,7 +449,6 @@ class DPService:
         if s is None:
             raise KeyError(f"unknown or expired session {sid}")
         self.stats["sessions_closed"] += 1
-        _telemetry.count("dp_service_sessions_closed_total")
         return {"sid": s.sid, "problem": s.problem, "appends": s.appends,
                 "extends": s.extends, "affinity": s.affinity,
                 "length": s.length}
@@ -523,14 +484,9 @@ class DPService:
                     self.stats["expired"] += 1
                     expired.append(t.tid)
                     self._unresolved.discard(t.tid)
-                    _telemetry.count("dp_service_expired_total")
-                    span = self._spans.pop(t.tid, None)
-                    if span is not None:
-                        span.meta["status"] = "expired"
-                        _telemetry.finish_span(span.add("expired"))
                     _backends.lru_put(self._results, t.tid, ServiceResult(
                         tid=t.tid, problem=t.problem, status="expired",
-                        latency_ms=(now - t.submitted_at) * 1e3, span=span),
+                        latency_ms=(now - t.submitted_at) * 1e3),
                         self.results_max)
                 else:
                     live.append(t)
@@ -594,7 +550,6 @@ class DPService:
                                       t.deadline if t.deadline is not None
                                       else float("inf"), t.tid))
             take, rest = queue[:budget], queue[budget:]
-            t_dispatch = _telemetry.clock() if _telemetry.enabled() else 0.0
             for t in take:
                 rid = self.engine.submit_spec(t.problem, t.spec,
                                               reconstruct=t.reconstruct,
@@ -602,10 +557,6 @@ class DPService:
                                               resume=t.resume,
                                               keep_table=t.keep_table)
                 self._inflight[rid] = t
-                t.t_dispatched = t_dispatch
-                span = self._spans.get(t.tid)
-                if span is not None:
-                    span.add("dispatched", t_dispatch)
             admitted += len(take)
             budget -= len(take)
             if rest:
@@ -629,30 +580,21 @@ class DPService:
             with _telemetry.trace_span("dp.resolve"):
                 self._resolve(responses, resolved)
             self.stats["service_steps"] += 1
-            _telemetry.set_gauge("dp_service_backlog", self.backlog())
-            _telemetry.set_gauge("dp_service_inflight", len(self._inflight))
-            _telemetry.set_gauge("dp_service_cache_size", len(self._cache))
             span.set_metadata(answered=len(resolved))
         return resolved
 
     def _resolve(self, responses: list, resolved: list) -> None:
-        """File a drain's responses: results, sessions, the answer cache and
-        per-request attribution. Appends each resolved tid to
-        ``resolved``."""
-        drain = self.engine.last_drain if _telemetry.enabled() else None
-        t_done = _telemetry.clock() if _telemetry.enabled() else 0.0
+        """File a drain's responses: results, sessions and the answer cache.
+        Appends each resolved tid to ``resolved``."""
         for resp in responses:
             t = self._inflight.pop(resp.rid)
             self._unresolved.discard(t.tid)
-            span = self._spans.pop(t.tid, None)
             res = ServiceResult(
                 tid=t.tid, problem=t.problem, status="done",
                 answer=resp.answer, solution=resp.solution,
                 backend=resp.backend,
                 latency_ms=(time.monotonic() - t.submitted_at) * 1e3,
-                span=span, extended=resp.extended, sid=t.sid)
-            if drain is not None:
-                self._observe_phases(t, resp, drain, span, t_done)
+                extended=resp.extended, sid=t.sid)
             if t.keep_table and resp.table is not None:
                 # index the solved table (cold or stitched) so the next
                 # append — this session's or any other's — warm-starts here
@@ -671,8 +613,6 @@ class DPService:
             _backends.lru_put(self._results, t.tid, res, self.results_max)
             resolved.append(t.tid)
             self.stats["completed"] += 1
-            _telemetry.count("dp_service_completed_total")
-            _telemetry.observe_ms("dp_service_latency_ms", res.latency_ms)
             rkey = (t.problem, resp.backend)
             self.routes[rkey] = self.routes.get(rkey, 0) + 1
             ckey = (t.problem, t.digest, t.reconstruct)
@@ -681,46 +621,6 @@ class DPService:
                                           solution=resp.solution,
                                           backend=resp.backend),
                               self.cache_size)
-
-    def _observe_phases(self, t: Ticket, resp, drain, span, t_done: float):
-        """Per-request latency attribution from the drain report: feed the
-        queue/dispatch/solve/traceback/decode histograms, and (``spans``
-        mode) put the drain's phases into the request's span at the times
-        they ended. Solve/traceback/decode are drain-level durations — each
-        request in the batch waited for the whole batched call, so the
-        drain's duration IS its latency contribution."""
-        phases = {
-            "queue": (t.t_dispatched - t.t_enqueued) * 1e3,
-            "dispatch": (drain.t_start - t.t_dispatched) * 1e3,
-        }
-        if not resp.extended:
-            phases["solve"] = drain.phases.get("solve", 0.0)
-        for ph in ("extend", "traceback", "decode"):
-            if ph in drain.phases:
-                phases[ph] = drain.phases[ph]
-        for ph, ms in phases.items():
-            _telemetry.observe_ms(f"dp_service_{ph}_ms", max(ms, 0.0))
-        if span is None:
-            return
-        span.meta.update(status="done", backend=resp.backend,
-                         batch_size=resp.batch_size, bucket=repr(drain.bucket),
-                         cold=drain.cold, sharded=drain.sharded)
-        if resp.extended:
-            span.meta.update(extended=True, affine=resp.affine)
-        tt = drain.t_start
-        span.add("batched", tt)
-        if drain.cold:
-            span.add("retraced", tt)
-        for phase, event in (("extend" if resp.extended else "solve",
-                              "extended" if resp.extended else "solved"),
-                             ("traceback", "traceback"),
-                             ("decode", "decoded")):
-            if phase in drain.bounds:
-                tt = drain.bounds[phase][1]
-                span.add(event, tt)
-        if resp.deduped:
-            span.add("dedup_fanout", tt)
-        _telemetry.finish_span(span.add("resolved", t_done))
 
     def run(self, backend: Optional[str] = None) -> dict:
         """Drive the loop until backlog and engine are empty; returns

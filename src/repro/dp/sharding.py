@@ -199,9 +199,4 @@ class ShardedDPEngine(DPEngine):
             argss, source, paths = None, None, None
         self.stats["sharded_drains"] += 1
         self.stats["padded_lanes"] += n_pad
-        rep = _telemetry.current_drain()
-        if rep is not None:
-            rep.sharded = True
-        _telemetry.count("dp_engine_sharded_drains_total")
-        _telemetry.count("dp_engine_padded_lanes_total", n_pad)
         return tables, argss, source, paths

@@ -53,14 +53,13 @@ def vmem_budget_bytes() -> int:
     return _envknobs.read("REPRO_VMEM_BUDGET")
 
 
-def _count_entry(fn: str, mode: str) -> None:
-    """Telemetry counter ``dp_kernel_<fn>_<mode>_total`` for one kernel-tier
-    entry call. Imported lazily at call time (never at module import — the
-    dp package pulls this module in through route registration) and a
-    guarded no-op below ``basic``."""
-    from repro.dp import telemetry
+#: ``(fn, mode)`` -> calls of the kernel-tier entry ``fn`` in ``mode``
+#: (``kernel_mode()``); read by the chip smoke to prove each kernel ran
+ENTRY_COUNTS: dict = {}
 
-    telemetry.count(f"dp_kernel_{fn}_{mode}_total")
+
+def _count_entry(fn: str, mode: str) -> None:
+    ENTRY_COUNTS[fn, mode] = ENTRY_COUNTS.get((fn, mode), 0) + 1
 
 
 def kernel_mode() -> str:

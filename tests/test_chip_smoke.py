@@ -34,15 +34,14 @@ def smoke():
 @pytest.fixture
 def chip_like_routing(monkeypatch):
     import repro.kernels as kernels
-    from repro.dp import telemetry
+    from repro.kernels import ops
 
     monkeypatch.setenv("REPRO_KERNELS", "interpret")
     monkeypatch.setenv("REPRO_VMEM_BUDGET", "24000")
     monkeypatch.setattr(kernels, "_mode_factor", lambda: 0.5)
     monkeypatch.setattr(kernels, "_tiled_mode_factor", lambda: 0.6)
-    prev = telemetry.configure("basic")
-    yield
-    telemetry.configure(prev)
+    # count only this test's kernel entries, not earlier tests' in the worker
+    monkeypatch.setattr(ops, "ENTRY_COUNTS", {})
 
 
 def test_one_chip_phase_serves_every_kernel_route(smoke, chip_like_routing):

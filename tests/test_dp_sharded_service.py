@@ -111,7 +111,6 @@ def _child() -> dict:
 def seen():
     env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS=f"--xla_force_host_platform_device_count={NDEV}")
-    env.pop("REPRO_TELEMETRY", None)
     p = subprocess.run([sys.executable, os.path.abspath(__file__)], env=env,
                        cwd=ROOT, capture_output=True, text=True,
                        timeout=CHILD_TIMEOUT_S)

@@ -1,8 +1,7 @@
-"""Telemetry layer (DESIGN.md §8): mode knobs, metrics registry, request
-spans, drain attribution, profiler spans, thread safety, exporters, and the
-service accounting invariant."""
+"""The timing layer (DESIGN.md §8): the log knob, the engine's and the
+service's ``stats`` counts, kernel entry counts, the trace log under
+concurrency, and the ``dp.*`` profiler spans of a captured service round."""
 import glob
-import json
 import threading
 
 import jax
@@ -15,20 +14,9 @@ from repro.dp.problem import spec_digest_bytes
 
 
 @pytest.fixture(autouse=True)
-def _telemetry_isolated(monkeypatch):
-    """Telemetry state is process-global (cached mode, registry, rings);
-    every test starts and ends at a clean ``off``."""
-    monkeypatch.delenv(telemetry.ENV_MODE, raising=False)
+def _log_env_unset(monkeypatch):
+    """No test here sees a ``REPRO_LOG`` from the calling environment."""
     monkeypatch.delenv(telemetry.ENV_LOG, raising=False)
-
-    def clean():
-        telemetry.reset()
-        telemetry.REGISTRY.reset()
-        telemetry.clear_spans()
-
-    clean()
-    yield
-    clean()
 
 
 def _mcm_payloads(n, rng=None, size=6):
@@ -37,31 +25,8 @@ def _mcm_payloads(n, rng=None, size=6):
 
 
 # ---------------------------------------------------------------------------
-# Mode / log knobs
+# Log knob
 # ---------------------------------------------------------------------------
-def test_mode_env_validated(monkeypatch):
-    monkeypatch.setenv(telemetry.ENV_MODE, "span")   # typo, not "spans"
-    telemetry.reset()
-    with pytest.raises(ValueError, match="REPRO_TELEMETRY"):
-        telemetry.mode()
-
-
-def test_mode_env_resolves_and_caches(monkeypatch):
-    monkeypatch.setenv(telemetry.ENV_MODE, "basic")
-    telemetry.reset()
-    assert telemetry.mode() == "basic"
-    assert telemetry.enabled("basic")
-    assert not telemetry.enabled("spans")
-
-
-def test_configure_validates_and_returns_previous():
-    assert telemetry.configure("spans") == "off"
-    assert telemetry.enabled("spans")
-    assert telemetry.configure("off") == "spans"
-    with pytest.raises(ValueError, match="invalid telemetry mode"):
-        telemetry.configure("verbose")
-
-
 def test_log_level_env_validated(monkeypatch):
     monkeypatch.setenv(telemetry.ENV_LOG, "loud")
     with pytest.raises(ValueError, match="REPRO_LOG"):
@@ -74,72 +39,8 @@ def test_get_logger_hierarchy():
 
 
 # ---------------------------------------------------------------------------
-# Registry primitives
-# ---------------------------------------------------------------------------
-def test_counter_is_monotonic():
-    c = telemetry.REGISTRY.counter("t_total")
-    c.inc()
-    c.inc(2)
-    assert c.value == 3
-    with pytest.raises(ValueError, match="cannot decrease"):
-        c.inc(-1)
-
-
-def test_metric_kind_collision_raises():
-    telemetry.REGISTRY.counter("t_name")
-    with pytest.raises(ValueError, match="already registered"):
-        telemetry.REGISTRY.gauge("t_name")
-
-
-def test_histogram_quantiles_clamped_to_observed():
-    h = telemetry.REGISTRY.histogram("t_ms", buckets=(1.0, 10.0, 100.0))
-    for v in (2.0, 3.0, 4.0, 5.0, 200.0):
-        h.observe(v)
-    assert h.count == 5
-    assert 2.0 <= h.quantile(0.5) <= 10.0
-    assert h.quantile(0.99) <= 200.0      # clamped to observed max
-    assert h.quantile(0.0) >= 2.0         # clamped to observed min
-    d = h.to_dict()
-    assert d["count"] == 5 and d["buckets"][-1] == ["+inf", 1]
-
-
-def test_helpers_are_noop_when_off():
-    telemetry.count("t_off_total")
-    telemetry.observe_ms("t_off_ms", 1.0)
-    telemetry.set_gauge("t_off_gauge", 1.0)
-    assert telemetry.REGISTRY.counters() == {}
-    assert telemetry.new_span(0, "mcm") is None
-
-
-def test_registry_source_absorbs_engine_stats():
-    telemetry.configure("basic")
-    eng = dp.DPEngine(max_batch=8)
-    eng.submit("mcm", dims=[4, 5, 6, 7])
-    eng.run()
-    sources = telemetry.REGISTRY.sources()
-    row = next(v for k, v in sources.items() if k.startswith("dp_engine/"))
-    assert row["completed"] == 1           # the compatibility stats view
-
-
-# ---------------------------------------------------------------------------
 # Thread safety
 # ---------------------------------------------------------------------------
-def test_registry_counter_thread_safe():
-    telemetry.configure("basic")
-    n_threads, per = 8, 500
-
-    def worker():
-        for _ in range(per):
-            telemetry.count("t_conc_total")
-
-    threads = [threading.Thread(target=worker) for _ in range(n_threads)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
-    assert telemetry.REGISTRY.counter("t_conc_total").value == n_threads * per
-
-
 def test_trace_log_concurrent_append_and_drain():
     backends.drain_trace_log()
     n_threads, per = 4, 200
@@ -171,103 +72,25 @@ def test_trace_log_concurrent_append_and_drain():
 
 
 # ---------------------------------------------------------------------------
-# Engine drains: counters + drain reports
+# Engine drains: stats counts
 # ---------------------------------------------------------------------------
 def test_counters_monotonic_across_drains():
-    telemetry.configure("basic")
     eng = dp.DPEngine(max_batch=4)
     seen = []
     for kw in _mcm_payloads(6):
         eng.submit("mcm", **kw)
     while eng.pending():
         eng.step()
-        c = telemetry.REGISTRY.counters()
-        seen.append((c["dp_engine_drains_total"],
-                     c["dp_engine_requests_total"]))
+        seen.append((eng.stats["device_batches"], eng.stats["completed"]))
     assert seen == sorted(seen)            # never decreases
     assert seen[-1][0] == len(seen)        # one drain per step
     assert seen[-1][1] == 6
-
-
-def test_drain_report_phases():
-    telemetry.configure("basic")
-    eng = dp.DPEngine(max_batch=8)
-    eng.submit("mcm", reconstruct=True, dims=[4, 5, 6, 7, 8])
-    eng.run()
-    rep = eng.last_drain
-    assert rep is not None and rep.backend
-    assert {"solve", "traceback", "decode"} <= set(rep.phases)
-    assert all(ms >= 0.0 for ms in rep.phases.values())
-    hists = telemetry.REGISTRY.histograms()
-    assert hists["dp_engine_solve_ms"].count == 1
-    assert hists["dp_engine_traceback_ms"].count == 1
-
-
-# ---------------------------------------------------------------------------
-# Service spans
-# ---------------------------------------------------------------------------
-def test_completed_poll_returns_span_with_phase_events():
-    telemetry.configure("spans")
-    svc = dp.DPService(max_batch=8, mesh=None)
-    tid = svc.submit("mcm", reconstruct=True, dims=[4, 5, 6, 7])
-    res = svc.run()[tid]
-    span = res.span
-    assert span is not None and span.tid == tid
-    names = set(span.event_names())
-    # the ≥5-distinct-phase-events acceptance bar, comfortably cleared
-    assert {"admitted", "enqueued", "dispatched", "batched", "solved",
-            "traceback", "decoded", "resolved"} <= names
-    phases = span.phases()
-    assert {"queue", "dispatch", "solve", "traceback", "decode",
-            "total"} <= set(phases)
-    assert span.meta["backend"] == res.backend
-    ts = [t for _, t in span.events]
-    assert ts == sorted(ts)                # one monotonic timebase
-    # the completed span also landed in the export ring
-    assert any(s["tid"] == tid for s in telemetry.spans_snapshot())
-
-
-def test_cache_hit_span():
-    telemetry.configure("spans")
-    svc = dp.DPService(max_batch=8, mesh=None)
-    kw = {"dims": [4, 5, 6, 7]}
-    first = svc.submit("mcm", **kw)
-    svc.run()[first]
-    hit = svc.submit("mcm", **kw)
-    res = svc.poll(hit)
-    assert res.cached
-    assert "cache_hit" in res.span.event_names()
-    assert res.span.meta["cached"] is True
-
-
-def test_expired_span():
-    telemetry.configure("spans")
-    svc = dp.DPService(max_batch=8, mesh=None)
-    tid = svc.submit("mcm", deadline_ms=0.0001, dims=[4, 5, 6, 7])
-    import time
-    time.sleep(0.002)
-    res = svc.run()[tid]
-    assert res.status == "expired"
-    assert "expired" in res.span.event_names()
-
-
-def test_per_phase_service_histograms():
-    telemetry.configure("basic")      # histograms need no span machinery
-    svc = dp.DPService(max_batch=8, mesh=None)
-    for kw in _mcm_payloads(5):
-        svc.submit("mcm", **kw)
-    svc.run()
-    hists = telemetry.REGISTRY.histograms()
-    for ph in ("queue", "dispatch", "solve"):
-        assert hists[f"dp_service_{ph}_ms"].count >= 5, ph
-    assert hists["dp_service_latency_ms"].count == 5
 
 
 # ---------------------------------------------------------------------------
 # Service accounting invariant
 # ---------------------------------------------------------------------------
 def test_submitted_balances_under_mixed_traffic():
-    telemetry.configure("spans")
     svc = dp.DPService(max_batch=4, max_pending=6, mesh=None)
     rng = np.random.default_rng(1)
     shed = 0
@@ -289,64 +112,15 @@ def test_submitted_balances_under_mixed_traffic():
 
 
 # ---------------------------------------------------------------------------
-# Routing is observability-independent
+# Kernel entry counts
 # ---------------------------------------------------------------------------
-def test_off_mode_routing_bit_identical():
-    """REPRO_TELEMETRY must be observability only: same traffic, same
-    routes, same answers with it off and on."""
-    def leg():
-        from repro.dp import autotune
-        autotune.reset()
-        eng = dp.DPEngine(max_batch=8, feedback=False)
-        rids = [eng.submit("mcm", **kw) for kw in _mcm_payloads(4)]
-        out = eng.run()
-        return [(out[r].backend, out[r].answer) for r in rids]
-
-    telemetry.configure("off")
-    off = leg()
-    telemetry.configure("spans")
-    spans = leg()
-    assert off == spans
-
-
-# ---------------------------------------------------------------------------
-# Exporters
-# ---------------------------------------------------------------------------
-def test_snapshot_and_save(tmp_path):
-    telemetry.configure("spans")
-    svc = dp.DPService(max_batch=8, mesh=None)
-    tid = svc.submit("mcm", dims=[4, 5, 6, 7])
-    svc.run()[tid]
-    snap = telemetry.snapshot()
-    assert snap["mode"] == "spans"
-    assert snap["counters"]["dp_service_completed_total"] == 1
-    assert "dp_service_latency_ms" in snap["histograms"]
-    assert any(s["tid"] == tid for s in snap["spans"])
-    path = telemetry.save_snapshot(str(tmp_path / "snap.json"))
-    assert json.load(open(path))["mode"] == "spans"
-
-
-def test_prometheus_exposition_format():
-    telemetry.configure("basic")
-    telemetry.count("t_reqs_total", 3)
-    telemetry.set_gauge("t_depth", 7)
-    telemetry.observe_ms("t_lat_ms", 12.0)
-    text = telemetry.to_prometheus()
-    assert "# TYPE t_reqs_total counter\nt_reqs_total 3" in text
-    assert "# TYPE t_depth gauge\nt_depth 7" in text
-    assert "# TYPE t_lat_ms histogram" in text
-    assert 't_lat_ms_bucket{le="+Inf"} 1' in text
-    assert "t_lat_ms_count 1" in text
-
-
-def test_kernel_entry_counter():
-    telemetry.configure("basic")
+def test_kernel_entry_counter(monkeypatch):
     from repro.kernels import ops
+    monkeypatch.setattr(ops, "ENTRY_COUNTS", {})
     x = np.zeros((4, 4), np.float32)
     ops.tropical_matmul(x, x)
     mode = ops.kernel_mode()
-    assert telemetry.REGISTRY.counters()[
-        f"dp_kernel_tropical_matmul_{mode}_total"] == 1
+    assert ops.ENTRY_COUNTS == {("tropical_matmul", mode): 1}
 
 
 # ---------------------------------------------------------------------------
@@ -503,23 +277,3 @@ def test_capture_counts_compiles_on_first_drain_only(gotoh_capture):
     assert compiles[0] >= 1
     assert compiles[1:] == [0] * (_ROUNDS - 1)
 
-
-def test_drain_report_phase_bounds_are_real_times():
-    """A drain report keeps each phase's start and end; the request's span
-    places ``solved``/``traceback``/``decoded`` at those ends."""
-    telemetry.configure("spans")
-    svc = dp.DPService(max_batch=8, mesh=None)
-    tid = svc.submit("mcm", reconstruct=True, dims=[4, 5, 6, 7, 8])
-    res = svc.run()[tid]
-    rep = svc.engine.last_drain
-    assert set(rep.bounds) == {"solve", "traceback", "decode"}
-    solve, tb, dec = (rep.bounds[p] for p in ("solve", "traceback",
-                                               "decode"))
-    assert rep.t_start <= solve[0] <= solve[1] <= tb[0] <= tb[1] \
-        <= dec[0] <= dec[1]
-    for phase, (t0, t1) in rep.bounds.items():
-        assert rep.phases[phase] == pytest.approx((t1 - t0) * 1e3)
-    events = dict(res.span.events)
-    assert events["solved"] == solve[1]
-    assert events["traceback"] == tb[1]
-    assert events["decoded"] == dec[1]
